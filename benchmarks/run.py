@@ -33,6 +33,9 @@ MODULES = [
 
 
 def main() -> None:
+    from repro.launch.device import init_compile_cache
+
+    init_compile_cache()
     args = sys.argv[1:]
     mods = args if args else MODULES
     print("name,us_per_call,derived")

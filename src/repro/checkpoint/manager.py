@@ -102,10 +102,13 @@ def save_tree(path: str, tree: Any, step: int) -> None:
             raise
 
 
-def restore_tree(path: str, like: Any, *, mesh=None, specs=None) -> Any:
+def restore_tree(path: str, like: Any, *, mesh=None, specs=None,
+                 host: bool = False) -> Any:
     """Load a snapshot; optionally re-shard onto ``mesh`` with ``specs``.
 
     ``like`` provides the pytree structure (its leaf values are ignored).
+    ``host=True`` keeps every leaf a NumPy array and never touches a JAX
+    backend — for supervisors that must leave the chip to their workers.
     """
     with open(os.path.join(path, _MANIFEST)) as f:
         manifest = json.load(f)
@@ -118,6 +121,8 @@ def restore_tree(path: str, like: Any, *, mesh=None, specs=None) -> Any:
     leaves = [_from_npz_safe(data[f"leaf_{i}"], manifest["dtypes"][i],
                              manifest["shapes"][i])
               for i in range(len(names))]
+    if host:
+        return jax.tree_util.tree_unflatten(treedef, leaves)
     if mesh is not None and specs is not None:
         from jax.sharding import NamedSharding, PartitionSpec
         spec_flat = jax.tree_util.tree_flatten(

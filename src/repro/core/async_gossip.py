@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .consensus import realized_round_weights, safe_debias_scale
+from .linalg import PRECISION
 from .metrics import CommLedger
 from .topology import Graph, local_degree_weights
 
@@ -77,11 +78,11 @@ def masked_async_rounds(w, adj, awake, t_c, z_stack):
         both = jnp.outer(a, a)
         w_off, dd = realized_round_weights(wz, both, off)
         w_round = w_off + jnp.diag(dd)
-        z_next = jnp.einsum("ij,j...->i...", w_round, z)
+        z_next = jnp.einsum("ij,j...->i...", w_round, z, precision=PRECISION)
         # only column 0 of the realized product is ever read (the debias
         # weight), so carry the (N,) vector p = Pi W e_1, not the (N, N)
         # product — O(N^2) per round instead of O(N^3)
-        p_next = w_round @ p
+        p_next = jnp.matmul(w_round, p, precision=PRECISION)
         sends = jnp.sum(jnp.where(off & both, adj, 0.0))
         count = jnp.sum(a.astype(jnp.float32))
         z = jnp.where(live, z_next, z)

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map
+from .linalg import PRECISION
 from .sparse import SparseW, auto_sparse
 from .topology import Graph, local_degree_weights, ring
 from .metrics import CommLedger
@@ -115,7 +115,7 @@ def gossip_mix(wz, z):
     """
     if isinstance(wz, SparseW):
         return wz.mix(z)
-    return jnp.einsum("ij,j...->i...", wz, z)
+    return jnp.einsum("ij,j...->i...", wz, z, precision=PRECISION)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -168,7 +168,8 @@ def debias_table(w, t_max: int) -> jnp.ndarray:
     def step(p, _):
         # SparseW is symmetric by contract, so W^T p is the ordinary mix;
         # the dense branch keeps the exact original matvec op
-        p_next = w.mix(p) if isinstance(w, SparseW) else w.T @ p
+        p_next = (w.mix(p) if isinstance(w, SparseW)
+                  else jnp.matmul(w.T, p, precision=PRECISION))
         return p_next, p_next
 
     _, rows = jax.lax.scan(step, e1, None, length=t_max)
@@ -441,7 +442,8 @@ class SpmdConsensus:
         def round_(zz, _):
             allz = jax.lax.all_gather(zz, axis)            # (N, ...)
             row = jax.lax.dynamic_slice_in_dim(wj, idx, 1, 0)[0]  # (N,)
-            mixed = jnp.tensordot(row, allz, axes=(0, 0))
+            mixed = jnp.tensordot(row, allz, axes=(0, 0),
+                                  precision=PRECISION)
             return mixed, None
 
         out, _ = jax.lax.scan(round_, z, None, length=t_c)
@@ -478,7 +480,8 @@ class SpmdConsensus:
         def round_(zz, i):
             allz = jax.lax.all_gather(zz, axis)            # (N, ...)
             row = jax.lax.dynamic_slice_in_dim(wj, idx, 1, 0)[0]  # (N,)
-            mixed = jnp.tensordot(row, allz, axes=(0, 0))
+            mixed = jnp.tensordot(row, allz, axes=(0, 0),
+                                  precision=PRECISION)
             return jnp.where(i < t_c, mixed, zz), None
 
         out, _ = jax.lax.scan(round_, z, jnp.arange(t_max))
@@ -530,7 +533,8 @@ class SpmdConsensus:
             return zz[None]
 
         spec = P(axis)
-        fn = shard_map(local_fn, mesh=mesh, in_specs=(spec,), out_specs=spec)
+        fn = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec,),
+                           out_specs=spec)
         return jax.jit(fn)
 
 

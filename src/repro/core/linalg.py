@@ -10,7 +10,18 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["cholesky_qr", "cholesky_qr2", "orthonormal_init", "eigh_topr"]
+__all__ = ["PRECISION", "cholesky_qr", "cholesky_qr2", "orthonormal_init",
+           "eigh_topr"]
+
+# Precision of every f32 matmul on the S-DOT path: the gossip, the local
+# apply (cov einsum and the Pallas gram kernel), CholeskyQR2 and the error
+# trace. HIGHEST is full f32 (several bf16 passes on the TPU MXU). At
+# DEFAULT a TPU rounds f32 operands to bf16, and CholeskyQR2 squares the
+# condition number of what it rounds: on a TPU v5e the paper's ImageNet and
+# LFW rows (chip_smoke.py) then ended at subspace errors of 1.4e-4 and
+# 1.3e-4, against a float64 floor of 1.3e-7 and 5.2e-9. The CPU computes
+# f32 either way.
+PRECISION = jax.lax.Precision.HIGHEST
 
 
 def cholesky_qr(v: jnp.ndarray, eps: float = 0.0):
@@ -19,7 +30,7 @@ def cholesky_qr(v: jnp.ndarray, eps: float = 0.0):
     Gram is computed in float32 at minimum for stability.
     """
     acc = jnp.promote_types(v.dtype, jnp.float32)
-    g = (v.astype(acc).T @ v.astype(acc))
+    g = jnp.matmul(v.astype(acc).T, v.astype(acc), precision=PRECISION)
     if eps:
         g = g + eps * jnp.eye(g.shape[0], dtype=acc)
     r = jnp.linalg.cholesky(g).T  # upper triangular
@@ -31,7 +42,7 @@ def cholesky_qr2(v: jnp.ndarray, eps: float = 1e-12):
     """CholeskyQR2: two passes; orthogonality error ~ machine eps."""
     q1, r1 = cholesky_qr(v, eps=eps)
     q2, r2 = cholesky_qr(q1, eps=0.0)
-    return q2, r2 @ r1
+    return q2, jnp.matmul(r2, r1, precision=PRECISION)
 
 
 def orthonormal_init(key, d: int, r: int, dtype=jnp.float32) -> jnp.ndarray:

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .linalg import PRECISION
+
 __all__ = [
     "subspace_error",
     "subspace_error_from_cross",
@@ -28,7 +30,8 @@ def subspace_error(q_true, q_hat) -> jnp.ndarray:
 
     Invariant to right-rotation of either argument. 0 iff span(Q)==span(Qhat).
     """
-    return subspace_error_from_cross(q_true.T @ q_hat)
+    return subspace_error_from_cross(
+        jnp.matmul(q_true.T, q_hat, precision=PRECISION))
 
 
 def subspace_error_from_cross(cross) -> jnp.ndarray:

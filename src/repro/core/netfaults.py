@@ -57,6 +57,7 @@ import numpy as np
 
 from .consensus import (_record_engine_metrics, debias_table,
                         realized_round_weights, safe_debias_scale)
+from .linalg import PRECISION
 from .metrics import CommLedger
 from .sparse import SparseW, auto_sparse
 from .topology import Graph, local_degree_weights
@@ -223,8 +224,9 @@ def _faulty_round(wz, adj_b, off, params, up_pair, node_up, z, p, ge,
     # split form: the diagonal applies each node's OWN (uncorrupted) state,
     # off-diagonal weights apply the screened messages
     z_next = dd.reshape(bshape) * z + jnp.einsum("ij,j...->i...", w_off,
-                                                 msg_clean)
-    p_next = dd * p + w_off @ p
+                                                 msg_clean,
+                                                 precision=PRECISION)
+    p_next = dd * p + jnp.matmul(w_off, p, precision=PRECISION)
     sends = jnp.sum(jnp.where(off & mask, 1.0, 0.0))
     count = jnp.sum(node_up)
     return z_next, p_next, ge_next, sends, count

@@ -70,7 +70,7 @@ from ..obs import get_journal
 from .metrics import CommLedger
 
 __all__ = ["RunState", "Program", "sync_body", "run_monolithic",
-           "run_chunked", "run_sweep", "async_ledger"]
+           "lower_monolithic", "run_chunked", "run_sweep", "async_ledger"]
 
 
 def sync_body(inner):
@@ -340,6 +340,16 @@ def _run(program: Program, manager: Optional[CheckpointManager],
 def run_monolithic(program: Program):
     """The whole run as one jitted scan chunk (the fused default path)."""
     return _run(program, None, max(program.t_outer, 1), None)
+
+
+def lower_monolithic(program: Program) -> jax.stages.Lowered:
+    """The chunk program ``run_monolithic`` dispatches for ``program``,
+    lowered but not run: ``.compile()`` times the compile, and the
+    compiled text shows which kernels the run contains."""
+    return _chunk_program.lower(
+        _init_state(program), program.operands,
+        jnp.asarray(program.xs, jnp.int32), build=program.build_body,
+        statics=program.statics, case_axes=None, seeded=False)
 
 
 def run_chunked(program: Program, manager: Optional[CheckpointManager],

@@ -47,12 +47,11 @@ from jax.sharding import PartitionSpec as P
 
 from . import runtime
 from .async_gossip import masked_async_rounds
-from .compat import shard_map
 from .consensus import (DenseConsensus, consensus_schedule, debias_table,
                         debiased_gossip)
 from .netfaults import (masked_faulty_rounds, realized_debias,
                         sample_fault_blocks)
-from .linalg import cholesky_qr2, orthonormal_init
+from .linalg import PRECISION, cholesky_qr2, orthonormal_init
 from .metrics import CommLedger, mean_subspace_error, subspace_error
 from ..kernels import ops as kops
 
@@ -75,7 +74,7 @@ class SDOTResult:
 
 def local_cov_apply(covs: jnp.ndarray, q_nodes: jnp.ndarray) -> jnp.ndarray:
     """Step 5 of Alg. 1 at every node: Z_i = M_i Q_i. covs: (N,d,d)."""
-    return jnp.einsum("nde,ner->ndr", covs, q_nodes)
+    return jnp.einsum("nde,ner->ndr", covs, q_nodes, precision=PRECISION)
 
 
 def _stack_data(xs: Sequence[jnp.ndarray]):
@@ -508,7 +507,7 @@ def sdot_spmd(
     def local_fn(cov, q0, sched, tab, qt):
         # cov/q0: (1, d, d) / (1, d, r) local blocks; sched/tab/qt replicated
         def outer(q, tc):
-            z = cov[0] @ q
+            z = jnp.matmul(cov[0], q, precision=PRECISION)
             z = engine.gossip_rounds_masked(z, tc, t_max)
             z = engine.debias_by_table(z, tab, tc)
             q_new = cholesky_qr2(z)[0]
@@ -520,9 +519,9 @@ def sdot_spmd(
         return qf[None], errs
 
     spec, rep = P(engine.axis), P()
-    fn = shard_map(local_fn, mesh=engine.mesh,
-                   in_specs=(spec, spec, rep, rep, rep),
-                   out_specs=(spec, rep))
+    fn = jax.shard_map(local_fn, mesh=engine.mesh,
+                       in_specs=(spec, spec, rep, rep, rep),
+                       out_specs=(spec, rep))
     q_nodes, errs = jax.jit(fn)(covs, q_nodes, sched_dev, table, q_arg)
 
     ledger = CommLedger()

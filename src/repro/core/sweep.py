@@ -128,7 +128,8 @@ class SweepResult:
         order — contiguous seed slices from ``slice_seed_shards``, so
         concatenation reproduces the single-process sweep's seed order
         exactly and the merged result is arithmetically identical to it
-        (bitwise when the shard lane widths match).
+        (bitwise when the shard lane widths match). The merge is NumPy
+        only, so a launcher can merge without opening a JAX backend.
 
         Two classes of operator error are rejected instead of silently
         concatenated: shards published under DIFFERENT spec fingerprints
@@ -166,7 +167,7 @@ class SweepResult:
             if ragged:
                 node_counts = np.asarray(tree["node_counts"])
         return cls(
-            q=jnp.asarray(np.concatenate(qs, axis=seed_axis)),
+            q=np.concatenate(qs, axis=seed_axis),
             error_traces=(np.concatenate(errs, axis=seed_axis)
                           if has_err else None),
             ledger=ledger,

@@ -13,9 +13,9 @@ r = 128 this is comfortably compute-bound on the MXU.
 
 Grid layout: (n_blocks,) outer sequential grid walks column blocks; the
 (d x r) output block is revisited every step and accumulated in VMEM
-(TPU grids are sequential, so accumulation over the grid is safe). Both d and
-r must be padded to multiples of 128 by the wrapper (ops.py); bn is the
-column tile, chosen so (d*bn + d*r + bn*r) * 4 bytes fits VMEM.
+(TPU grids are sequential, so accumulation over the grid is safe). bn is the
+column tile; ops.py checks ``gram_vmem_bytes`` against ``GRAM_VMEM_LIMIT``
+before it picks the kernel.
 """
 from __future__ import annotations
 
@@ -24,8 +24,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["gram_apply_pallas", "batched_gram_apply_pallas"]
+__all__ = ["gram_apply_pallas", "batched_gram_apply_pallas",
+           "gram_vmem_bytes", "GRAM_VMEM_LIMIT"]
+
+# Full f32 on the MXU, as on the rest of the S-DOT path (the reasoning is
+# at core/linalg.PRECISION; kernels import nothing from core).
+PRECISION = jax.lax.Precision.HIGHEST
+
+# Scoped VMEM the gram kernels may use (the v5e default is 16 MiB of its
+# 128 MiB). Full-f32 dots keep split copies of the X block: compiled for a
+# described v5e, d=2914 with 512-column blocks needs 18.3 MiB and d=8192
+# with 256-column blocks 27.8 MiB.
+GRAM_VMEM_LIMIT = 64 * 2**20
+
+
+def gram_vmem_bytes(d: int, r: int, block_n: int) -> int:
+    """Upper estimate of the gram kernels' scoped VMEM: the X block
+    double-buffered plus one full-f32 split copy, and the lane-padded Q and
+    V blocks double-buffered. It over-counts the compiler's own figure by
+    1.3-1.5x at d=2914..8192."""
+    lanes = -(-r // 128) * 128
+    return (3 * d * block_n + 4 * d * lanes) * 4
 
 
 def _gram_kernel(x_ref, q_ref, v_ref):
@@ -39,10 +60,10 @@ def _gram_kernel(x_ref, q_ref, v_ref):
     x = x_ref[...]          # (d, bn)
     q = q_ref[...]          # (d, r)
     s = jax.lax.dot_general(
-        x, q, (((0,), (0,)), ((), ())),
+        x, q, (((0,), (0,)), ((), ())), precision=PRECISION,
         preferred_element_type=jnp.float32)        # X_b^T Q: (bn, r)
     v = jax.lax.dot_general(
-        x, s, (((1,), (0,)), ((), ())),
+        x, s, (((1,), (0,)), ((), ())), precision=PRECISION,
         preferred_element_type=jnp.float32)        # X_b S: (d, r)
     v_ref[...] += v.astype(v_ref.dtype)
 
@@ -69,6 +90,8 @@ def gram_apply_pallas(x: jnp.ndarray, q: jnp.ndarray, *, block_n: int = 512,
         ],
         out_specs=pl.BlockSpec((d, r), lambda j: (0, 0)),   # V (accumulated)
         out_shape=jax.ShapeDtypeStruct((d, r), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=GRAM_VMEM_LIMIT),
         interpret=interpret,
     )(x, q)
     return out
@@ -90,10 +113,10 @@ def _batched_gram_kernel(x_ref, q_ref, v_ref):
     x = x_ref[0]            # (d, bn) — node i's column block
     q = q_ref[0]            # (d, r)  — node i's iterate
     s = jax.lax.dot_general(
-        x, q, (((0,), (0,)), ((), ())),
+        x, q, (((0,), (0,)), ((), ())), precision=PRECISION,
         preferred_element_type=jnp.float32)        # X_b^T Q: (bn, r)
     v = jax.lax.dot_general(
-        x, s, (((1,), (0,)), ((), ())),
+        x, s, (((1,), (0,)), ((), ())), precision=PRECISION,
         preferred_element_type=jnp.float32)        # X_b S: (d, r)
     v_ref[0, ...] += v.astype(v_ref.dtype)
 
@@ -126,6 +149,8 @@ def batched_gram_apply_pallas(x_stack: jnp.ndarray, q_stack: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((1, d, r), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_nodes, d, r), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=GRAM_VMEM_LIMIT),
         interpret=interpret,
     )(x_stack, q_stack)
     return out

@@ -4,33 +4,75 @@ These handle padding to hardware-aligned tiles, GQA head expansion, CPU
 fallback (interpret mode or the pure-jnp oracle), and normalization — so the
 rest of the codebase never calls pallas_call directly.
 
-On this CPU-only container the kernels run with ``interpret=True`` (the
-kernel body executes in Python against the same BlockSpec tiling the TPU
-would use); on TPU the identical code lowers to Mosaic.
+Off TPU the kernels run with ``interpret=True`` (the kernel body executes in
+Python against the same BlockSpec tiling the TPU would use); on TPU the
+identical code lowers to Mosaic and ``interpret`` defaults to False.
+
+Every wrapper with a VMEM guard reports its dispatch through a ``*_path``
+function ('pallas' or a named fallback). On TPU a tripped guard also warns
+at trace time, so a run that was meant to use a kernel never falls back to
+the jnp oracle unseen.
 """
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
 
 from . import ref
-from .ell_spmm import ell_spmm_pallas
+from .ell_spmm import (ell_spmm_pallas, ell_spmm_smem_bytes,
+                       ell_spmm_vmem_bytes)
 from .flash_attention import flash_attention_pallas
 from .gram_qr import gram_qr_pallas
-from .gram_update import batched_gram_apply_pallas, gram_apply_pallas
+from .gram_update import (GRAM_VMEM_LIMIT, batched_gram_apply_pallas,
+                          gram_apply_pallas, gram_vmem_bytes)
 from .slab_ops import (batched_slab_apply_pallas, batched_slab_tq_pallas,
                        grid_block_apply_pallas, grid_block_tq_pallas)
 
 __all__ = ["gram_apply", "batched_gram_apply", "batched_slab_tq",
            "batched_slab_apply", "grid_block_tq", "grid_block_apply",
            "gram_qr", "flash_attention", "ell_spmm", "ell_spmm_path",
-           "ell_densify_wins", "on_tpu"]
+           "ell_block_rows", "ell_densify_wins", "batched_gram_apply_path",
+           "on_tpu"]
 
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+# Per-kernel VMEM guard for the blocks of the slab and grid kernels: one
+# block set, not counting double buffering.
+_VMEM_GUARD = 8 * 2**20
+
+# Double-buffered VMEM the ELL kernel may claim. Compiled for a described
+# v5e, its pipeline was accepted at 45.6 MB (N=44,000, K=128, f32) and
+# refused at 49.8 MB (N=48,000); 40 MiB keeps a margin below that.
+_ELL_VMEM_BUDGET = 40 * 2**20
+
+# SMEM (1 MiB on v5e) the ELL kernel's index tiles may claim. Compiled for a
+# described v5e, 768 KiB (L=128, 256-row blocks) and 896 KiB (L=300,
+# 128-row blocks) were accepted and 1.25 MiB (L=129, 256 rows) refused.
+_ELL_SMEM_BUDGET = 960 * 2**10
+
+
+def _guarded_path(kernel: str, use_pallas: bool | None, need_bytes: int,
+                  budget: int = _VMEM_GUARD, memory: str = "VMEM") -> str:
+    """'pallas' | 'fallback_ref' (kernel off) | 'fallback_vmem' (guard
+    tripped). A tripped guard on TPU warns: the oracle then runs in place
+    of the kernel the caller asked for."""
+    if use_pallas is None:
+        use_pallas = on_tpu()
+    if not use_pallas:
+        return "fallback_ref"
+    if need_bytes <= budget:
+        return "pallas"
+    if on_tpu():
+        warnings.warn(f"{kernel}: {need_bytes} B of {memory} blocks exceed "
+                      f"the {budget} B guard; running the jnp oracle instead "
+                      f"of the Pallas kernel", stacklevel=3)
+    return "fallback_vmem"
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -52,12 +94,22 @@ def gram_apply(x: jnp.ndarray, q: jnp.ndarray, *, block_n: int = 512,
     normalizer uses the true n.
     """
     d, n = x.shape
-    if not use_pallas or d * block_n * 4 > 8 * 2**20:  # VMEM guard: fall back
+    if _guarded_path("gram_apply", use_pallas,
+                     gram_vmem_bytes(d, q.shape[-1], block_n),
+                     GRAM_VMEM_LIMIT) != "pallas":
         return ref.gram_apply_ref(x, q)
     interp = (not on_tpu()) if interpret is None else interpret
     xp = _pad_to(x, 1, block_n)
     v = gram_apply_pallas(xp, q, block_n=block_n, interpret=interp)
     return (v / n).astype(q.dtype)
+
+
+def batched_gram_apply_path(d: int, r: int, block_n: int = 512,
+                            use_pallas: bool | None = None) -> str:
+    """Which path ``batched_gram_apply`` takes for (d, r): 'pallas' |
+    'fallback_ref' | 'fallback_vmem' (host-side mirror of its dispatch)."""
+    return _guarded_path("batched_gram_apply", use_pallas,
+                         gram_vmem_bytes(d, r, block_n), GRAM_VMEM_LIMIT)
 
 
 @functools.partial(jax.jit,
@@ -80,10 +132,8 @@ def batched_gram_apply(x_stack: jnp.ndarray, q_stack: jnp.ndarray,
     exercise the kernel itself off-TPU.
     """
     n_nodes, d, n = x_stack.shape
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    vmem_bytes = (d * block_n + 2 * d * q_stack.shape[-1]) * 4
-    if not use_pallas or vmem_bytes > 8 * 2**20:
+    if batched_gram_apply_path(d, q_stack.shape[-1], block_n,
+                               use_pallas) != "pallas":
         return ref.batched_gram_apply_ref(x_stack, q_stack, n_true)
     interp = (not on_tpu()) if interpret is None else interpret
     xp = _pad_to(x_stack, 2, block_n)
@@ -111,11 +161,9 @@ def batched_slab_tq(x_stack: jnp.ndarray, q_stack: jnp.ndarray, *,
     time, bloating the fused scan's XLA program on CPU for no win).
     """
     n_nodes, d, n = x_stack.shape
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    vmem_bytes = (d * block_n + d * q_stack.shape[-1]
-                  + block_n * q_stack.shape[-1]) * 4
-    if not use_pallas or vmem_bytes > 8 * 2**20:
+    r = q_stack.shape[-1]
+    vmem_bytes = (d * block_n + d * r + block_n * r) * 4
+    if _guarded_path("batched_slab_tq", use_pallas, vmem_bytes) != "pallas":
         return ref.batched_slab_tq_ref(x_stack, q_stack)
     interp = (not on_tpu()) if interpret is None else interpret
     xp = _pad_to(x_stack, 2, block_n)
@@ -135,11 +183,10 @@ def batched_slab_apply(x_stack: jnp.ndarray, s_stack: jnp.ndarray, *,
     together, so padded columns of X multiply zero rows of S — exact.
     """
     n_nodes, d, n = x_stack.shape
-    if use_pallas is None:
-        use_pallas = on_tpu()
     r = s_stack.shape[-1]
     vmem_bytes = (d * block_n + block_n * r + d * r) * 4
-    if not use_pallas or vmem_bytes > 8 * 2**20:
+    if _guarded_path("batched_slab_apply", use_pallas,
+                     vmem_bytes) != "pallas":
         return ref.batched_slab_apply_ref(x_stack, s_stack)
     interp = (not on_tpu()) if interpret is None else interpret
     xp = _pad_to(x_stack, 2, block_n)
@@ -165,11 +212,9 @@ def grid_block_tq(x_grid: jnp.ndarray, q_stack: jnp.ndarray, *,
     CPU for no win — same rationale as batched_slab_tq).
     """
     i_rows, j_cols, d, n = x_grid.shape
-    if use_pallas is None:
-        use_pallas = on_tpu()
     r = q_stack.shape[-1]
     vmem_bytes = (d * block_n + d * r + block_n * r) * 4
-    if not use_pallas or vmem_bytes > 8 * 2**20:
+    if _guarded_path("grid_block_tq", use_pallas, vmem_bytes) != "pallas":
         return ref.grid_block_tq_ref(x_grid, q_stack)
     interp = (not on_tpu()) if interpret is None else interpret
     xp = _pad_to(x_grid, 3, block_n)
@@ -189,11 +234,10 @@ def grid_block_apply(x_grid: jnp.ndarray, s_stack: jnp.ndarray, *,
     padded together, so padded columns of X multiply zero rows of S — exact.
     """
     i_rows, j_cols, d, n = x_grid.shape
-    if use_pallas is None:
-        use_pallas = on_tpu()
     r = s_stack.shape[-1]
     vmem_bytes = (d * block_n + block_n * r + d * r) * 4
-    if not use_pallas or vmem_bytes > 8 * 2**20:
+    if _guarded_path("grid_block_apply", use_pallas,
+                     vmem_bytes) != "pallas":
         return ref.grid_block_apply_ref(x_grid, s_stack)
     interp = (not on_tpu()) if interpret is None else interpret
     xp = _pad_to(x_grid, 3, block_n)
@@ -222,15 +266,31 @@ def ell_densify_wins(n: int, ell_width: int) -> bool:
     return ell_width * _ELL_DENSE_RATIO >= n
 
 
+def ell_block_rows(ell_width: int, block_rows: int = 256) -> int:
+    """Rows per ELL kernel step: ``block_rows`` halved until the step's
+    SMEM index tiles fit (0 when even 8 rows do not)."""
+    while (block_rows >= 8 and ell_spmm_smem_bytes(block_rows, ell_width)
+           > _ELL_SMEM_BUDGET):
+        block_rows //= 2
+    return block_rows if block_rows >= 8 else 0
+
+
 def ell_spmm_path(n: int, ell_width: int, k: int,
-                  use_pallas: bool | None = None) -> str:
+                  use_pallas: bool | None = None,
+                  payload_dtype: str | None = None,
+                  block_rows: int = 256) -> str:
     """Which execution path ``ell_spmm`` will take for these shapes:
     'pallas' | 'fallback_gather' | 'fallback_scan' | 'fallback_dense'
     (host-side mirror of the traced dispatch below, for observability and
-    benchmarks)."""
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    if use_pallas and n * k * 4 <= 8 * 2**20:
+    benchmarks). The Pallas kernel keeps the whole gather source resident
+    and double-buffered, at 2 bytes/elem in bf16 payload mode, and runs
+    with ``ell_block_rows`` rows per step."""
+    itemsize = 2 if payload_dtype == "bfloat16" else 4
+    rows = ell_block_rows(ell_width, block_rows)
+    guard = ((ell_spmm_vmem_bytes(n, k, rows, itemsize), _ELL_VMEM_BUDGET,
+              "VMEM") if rows else
+             (ell_spmm_smem_bytes(8, ell_width), _ELL_SMEM_BUDGET, "SMEM"))
+    if _guarded_path("ell_spmm", use_pallas, *guard) == "pallas":
         return "pallas"
     if ell_densify_wins(n, ell_width):
         return "fallback_dense"
@@ -256,16 +316,17 @@ def ell_spmm(ell_idx: jnp.ndarray, ell_val: jnp.ndarray, diag: jnp.ndarray,
     each node's own diagonal term stays full precision.
 
     ``use_pallas=None`` auto-selects: the Pallas row-block gather kernel
-    on TPU (guarded by the full payload fitting VMEM), the gather/einsum
-    oracle elsewhere — densifying to a BLAS matmul when the padded width
-    approaches N (hub-heavy graphs) and degrading to a slot-at-a-time
-    scan when the (N, L, K) gathered block would be large (see
-    ``ell_spmm_path``).
+    on TPU (guarded by the double-buffered payload fitting VMEM), the
+    gather/einsum oracle elsewhere — densifying to a BLAS matmul when the
+    padded width approaches N (hub-heavy graphs) and degrading to a
+    slot-at-a-time scan when the (N, L, K) gathered block would be large
+    (see ``ell_spmm_path``).
     """
     n, k = z.shape
     ell_width = ell_idx.shape[1]
     z_src = z if payload_dtype is None else z.astype(payload_dtype)
-    path = ell_spmm_path(n, ell_width, k, use_pallas)
+    path = ell_spmm_path(n, ell_width, k, use_pallas, payload_dtype,
+                         block_rows)
     if path == "fallback_gather":
         return ref.ell_spmm_ref(ell_idx, ell_val, diag, z, z_src)
     if path == "fallback_dense":
@@ -273,6 +334,7 @@ def ell_spmm(ell_idx: jnp.ndarray, ell_val: jnp.ndarray, diag: jnp.ndarray,
     if path == "fallback_scan":
         return ref.ell_spmm_scan_ref(ell_idx, ell_val, diag, z, z_src)
     interp = (not on_tpu()) if interpret is None else interpret
+    block_rows = ell_block_rows(ell_width, block_rows)
     idx_p = _pad_to(ell_idx, 0, block_rows)
     val_p = _pad_to(ell_val, 0, block_rows)
     diag_p = _pad_to(diag, 0, block_rows)

@@ -1,7 +1,12 @@
 """Pure-jnp oracles for every Pallas kernel (ground truth for tests)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+# Oracle matmuls run at full f32 on the TPU too, as the kernels do (the
+# reasoning is at core/linalg.PRECISION; kernels import nothing from core).
+PRECISION = jax.lax.Precision.HIGHEST
 
 __all__ = ["gram_apply_ref", "batched_gram_apply_ref", "flash_attention_ref",
            "gram_qr_ref", "batched_slab_tq_ref", "batched_slab_apply_ref",
@@ -21,7 +26,8 @@ def ell_spmm_ref(ell_idx: jnp.ndarray, ell_val: jnp.ndarray,
     """
     msgs = jnp.take(z_src, ell_idx, axis=0).astype(jnp.float32)  # (N, L, K)
     acc = diag.astype(jnp.float32)[:, None] * z_own.astype(jnp.float32)
-    return acc + jnp.einsum("nl,nlk->nk", ell_val.astype(jnp.float32), msgs)
+    return acc + jnp.einsum("nl,nlk->nk", ell_val.astype(jnp.float32), msgs,
+                            precision=PRECISION)
 
 
 def ell_spmm_dense_ref(ell_idx: jnp.ndarray, ell_val: jnp.ndarray,
@@ -38,7 +44,8 @@ def ell_spmm_dense_ref(ell_idx: jnp.ndarray, ell_val: jnp.ndarray,
     w_off = jnp.zeros((n, n), jnp.float32).at[rows, ell_idx].add(
         ell_val.astype(jnp.float32))
     acc = diag.astype(jnp.float32)[:, None] * z_own.astype(jnp.float32)
-    return acc + w_off @ z_src.astype(jnp.float32)
+    return acc + jnp.matmul(w_off, z_src.astype(jnp.float32),
+                            precision=PRECISION)
 
 
 def ell_spmm_scan_ref(ell_idx: jnp.ndarray, ell_val: jnp.ndarray,
@@ -47,8 +54,6 @@ def ell_spmm_scan_ref(ell_idx: jnp.ndarray, ell_val: jnp.ndarray,
     """Slot-at-a-time twin of ``ell_spmm_ref``: scans the L slot columns so
     peak memory stays O(N K) instead of O(N L K) — the fallback ops.py
     selects when the gathered message block would be large."""
-    import jax
-
     acc0 = diag.astype(jnp.float32)[:, None] * z_own.astype(jnp.float32)
 
     def slot(acc, inp):
@@ -84,8 +89,9 @@ def batched_gram_apply_ref(x_stack: jnp.ndarray, q_stack: jnp.ndarray,
     """
     acc = jnp.promote_types(x_stack.dtype, jnp.float32)
     x32 = x_stack.astype(acc)
-    s = jnp.einsum("idn,idr->inr", x32, q_stack.astype(acc))
-    v = jnp.einsum("idn,inr->idr", x32, s)
+    s = jnp.einsum("idn,idr->inr", x32, q_stack.astype(acc),
+                   precision=PRECISION)
+    v = jnp.einsum("idn,inr->idr", x32, s, precision=PRECISION)
     v = v / n_true.astype(acc)[:, None, None]
     return v.astype(q_stack.dtype)
 

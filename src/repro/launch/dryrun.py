@@ -198,8 +198,6 @@ def run_cell(arch: str, shape_id: str, *, multi_pod: bool, psa: bool = False,
     t_compile = time.time() - t0
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):  # jax < 0.5 returns a per-program list
-        ca = ca[0] if ca else {}
     flops_dev = float(ca.get("flops", 0.0))
     bytes_dev = float(ca.get("bytes accessed", 0.0))
     try:

@@ -88,8 +88,6 @@ def _compile_cost(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
 
     compiled = lowered.compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):  # jax < 0.5 returns a per-program list
-        ca = ca[0] if ca else {}
     coll = collective_bytes(compiled.as_text(), mesh.size)
     return {
         "flops": float(ca.get("flops", 0.0)),

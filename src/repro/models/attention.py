@@ -37,9 +37,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
     if nq == 1 and nk == 1:
         # single-chunk fast path: same ops as one (q_step, k_step) pass, no
-        # scans — less dispatch for short sequences, and the only form whose
-        # VJP the legacy (jax<0.5) partial-auto partitioner can partition
-        # (scan VJPs CHECK-crash there; see core/compat.py)
+        # scans — less dispatch for short sequences
         qpos = jnp.arange(sq) + q_offset
         kpos = jnp.arange(skv)
         logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -62,11 +60,8 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
     kb = k.reshape(b, h, nk, k_chunk, hd).transpose(2, 0, 1, 3, 4)
     vb = v.reshape(b, h, nk, k_chunk, hd).transpose(2, 0, 1, 3, 4)
 
-    # Chunk indices ride in the scan CARRY (counters), not as iota xs:
-    # scanning over a jnp.arange CHECK-crashes the legacy (jax<0.5) SPMD
-    # partitioner inside partial-auto shard_map regions (IsManualSubgroup,
-    # iota device-group expansion) — see core/compat.py. Counter carries
-    # compute the identical positions.
+    # Chunk indices ride in the scan CARRY (counters), which compute the
+    # same positions as scanning over an iota.
     def q_step(qi, qblk):
         qpos = qi * q_chunk + jnp.arange(q_chunk) + q_offset      # (qc,)
         init = (jnp.int32(0),

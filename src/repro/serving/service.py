@@ -38,7 +38,9 @@ closes that loop as a sequence of deterministic *ticks*:
   to the uninterrupted run's.
 
 ``run_supervised`` wraps the loop in the fleet's supervision idiom:
-subprocess + heartbeat-staleness watchdog + relaunch with backoff.
+subprocess + heartbeat-staleness watchdog + relaunch with backoff. The
+supervisor itself never opens a JAX backend, so the chip is free for its
+child.
 ``run_smoke`` is the CI scenario: the same config run (a) fault-free,
 (b) under a kill/kill/hang FaultPlan with supervision, asserting the
 served trajectory is bit-identical and every restore matched the pinned
@@ -67,6 +69,8 @@ from ..core.linalg import cholesky_qr2, orthonormal_init
 from ..core.runtime import run_chunked
 from ..core.sdot import sdot_program
 from ..data.pipeline import drifting_eigengap_stream
+from ..launch.device import (EXIT_NO_DEVICE, init_compile_cache,
+                             require_expected_platform)
 from ..obs import install as obs_install
 from ..obs import metrics as obs_metrics
 from ..obs import obs_dir_for
@@ -612,6 +616,9 @@ def run_supervised(cfg: ServiceConfig, workdir: str, *,
             print(f"[supervisor] attempt {attempts}: rc={rc}")
         if rc == 0 and os.path.exists(final_path):
             break
+        if rc == EXIT_NO_DEVICE:
+            raise RuntimeError("service child found no TPU on a host that "
+                               "has one; another process holds the chip")
         if relaunches >= max_relaunches:
             raise RuntimeError(
                 f"service did not complete within {max_relaunches} "
@@ -630,6 +637,9 @@ def run_supervised(cfg: ServiceConfig, workdir: str, *,
 def run_smoke(workdir: str, *, verbose: bool = True) -> dict:
     """The CI serving-chaos scenario (see module docstring).
 
+    (b) runs first: until its supervised child is done, this process opens
+    no JAX backend, so on a one-chip host the child gets the chip.
+
     (a) fault-free in-process reference run;
     (b) the same config supervised under kill-mid-service /
         kill-mid-re-solve / hang faults — the served trajectory (swap
@@ -644,16 +654,6 @@ def run_smoke(workdir: str, *, verbose: bool = True) -> dict:
     """
     cfg = ServiceConfig()
     os.makedirs(workdir, exist_ok=True)
-
-    # (a) fault-free reference
-    ref_dir = os.path.join(workdir, "ref")
-    svc = PSAService(cfg, ref_dir).run()
-    ref = svc.finalize()
-    ref = service_summary(ref_dir)
-    assert ref["swaps"] >= 2, ref          # initial solve + >=1 drift swap
-    assert ref["gate_rejects"] == 0, ref
-    assert ref["max_staleness"] <= cfg.staleness_bound, ref
-    assert ref["queries"]["answered"] > 0, ref
 
     # (b) kill/kill/hang under supervision: trajectory must be identical
     chaos_dir = os.path.join(workdir, "chaos")
@@ -675,6 +675,16 @@ def run_smoke(workdir: str, *, verbose: bool = True) -> dict:
     t0 = time.perf_counter()
     chaos = run_supervised(cfg, chaos_dir, env=env, verbose=verbose)
     chaos_s = time.perf_counter() - t0
+
+    # (a) fault-free reference
+    ref_dir = os.path.join(workdir, "ref")
+    PSAService(cfg, ref_dir).run().finalize()
+    ref = service_summary(ref_dir)
+    assert ref["swaps"] >= 2, ref          # initial solve + >=1 drift swap
+    assert ref["gate_rejects"] == 0, ref
+    assert ref["max_staleness"] <= cfg.staleness_bound, ref
+    assert ref["queries"]["answered"] > 0, ref
+
     assert chaos["relaunches"] == 3, chaos
     # the served-subspace trajectory is BIT-identical to the reference
     assert chaos["served_sha256"] == ref["served_sha256"], (chaos, ref)
@@ -742,6 +752,12 @@ def main(argv=None) -> int:
         return 0
     if not args.run:
         ap.error("nothing to do (pass --run SPEC or --smoke)")
+    init_compile_cache()
+    try:
+        require_expected_platform()
+    except RuntimeError as e:
+        print(e, file=sys.stderr)
+        return EXIT_NO_DEVICE
     cfg = ServiceConfig.from_json(args.run)
     workdir = args.workdir or os.path.dirname(os.path.abspath(args.run))
     plan_path = os.environ.get(ENV_PLAN)

@@ -51,6 +51,13 @@ result is never recomputed (so a killed launcher resumes where it left
 off), a crashed shard is retried with backoff, and only then does the
 launch fail.
 
+One process per chip: the launcher never opens a JAX backend (it writes
+NumPy problem files and restores published shards into NumPy), so every
+chip stays free for the workers. A worker opens all the chips of its host,
+so on a host with TPU chips the launcher refuses more than one worker at a
+time, and a worker that nevertheless finds no TPU exits with
+``launch.device.EXIT_NO_DEVICE``, which stops the launch at once.
+
 Topologies/schedules travel as small JSON specs (``build_engine`` /
 ``build_schedule``) because graph constructions are seed-deterministic —
 the paper's experiment grid is fully reproducible from the spec file.
@@ -68,7 +75,6 @@ import time
 import zipfile
 from typing import Optional, Sequence, Union
 
-import jax.numpy as jnp
 import numpy as np
 
 from ..checkpoint.manager import restore_tree
@@ -76,6 +82,7 @@ from ..core.consensus import DenseConsensus, consensus_schedule
 from ..core.metrics import CommLedger
 from ..core.sweep import SweepResult, slice_seed_shards
 from ..core.topology import complete, erdos_renyi, ring, star, torus2d
+from ..launch.device import EXIT_NO_DEVICE, tpu_chips_on_host, tpu_expected
 from ..obs import Journal, obs_dir_for
 from .chaos import (ENV_PLAN, FaultPlan, net_faults_from_env,
                     validate_net_fault_doc)
@@ -152,15 +159,13 @@ def spec_fingerprint(spec: dict) -> int:
 
 def _result_like(spec: dict, with_resumed: bool = True):
     """Structure template for restore_tree (values are ignored)."""
-    like = {"q": jnp.zeros(()), "seeds": jnp.zeros(()),
-            "ledger": CommLedger(),
-            "spec_fp": jnp.zeros((), jnp.int32)}
+    like = {"q": 0, "seeds": 0, "ledger": CommLedger(), "spec_fp": 0}
     if with_resumed:
-        like["resumed_steps"] = jnp.zeros((), jnp.int32)
+        like["resumed_steps"] = 0
     if spec["has_q_true"]:
-        like["error_traces"] = jnp.zeros(())
+        like["error_traces"] = 0
     if spec["ragged"]:
-        like["node_counts"] = jnp.zeros(())
+        like["node_counts"] = 0
     return like
 
 
@@ -184,7 +189,8 @@ def _load_result(workdir: str, spec: dict, shard: int,
     tree = None
     for with_resumed in (True, False):
         try:
-            tree = restore_tree(path, _result_like(spec, with_resumed))
+            tree = restore_tree(path, _result_like(spec, with_resumed),
+                                host=True)
             break
         except _EXPECTED_RESTORE_ERRORS:
             continue
@@ -245,6 +251,16 @@ def _fail_report(workdir: str, proc: str, log_path: str) -> str:
     if trace:
         out += f"\njournal tail ({proc}):\n{trace}"
     return out
+
+
+def _no_device(rc, workdir: str, proc: str, log_path: str) -> None:
+    """Stop the launch when a worker found no TPU on a TPU host:
+    retrying cannot help while another process holds the chip."""
+    if rc == EXIT_NO_DEVICE:
+        raise RuntimeError(
+            f"{proc} found no TPU on a host that has one (another process "
+            f"holds the chip); one worker per TPU host. "
+            f"{_fail_report(workdir, proc, log_path)}")
 
 
 def _backoff(base: float, attempt: int, rng: random.Random) -> float:
@@ -339,6 +355,7 @@ def _supervise_pinned(spec_path, workdir, spec, pending, env, *, n_workers,
                     jl.event("shard_done", "launcher", shard=i,
                              launch_attempts=attempts[i], rc=rc)
                     continue
+                _no_device(rc, workdir, f"worker_s{i}", last_log[i])
                 if attempts[i] > retries:
                     raise RuntimeError(
                         f"sweep shard {i} failed after {retries + 1} "
@@ -393,6 +410,7 @@ def _supervise_elastic(spec_path, workdir, spec, pending, env, *, n_workers,
                     # not — consumes this slot's retry budget
                     rc = p.returncode
                     procs.pop(s)
+                    _no_device(rc, workdir, f"fleet_w{s}", last_log[s])
                     slot_attempts[s] += 1
                     jl.event("slot_exit", "launcher", slot=s, rc=rc,
                              slot_attempts=slot_attempts[s])
@@ -506,6 +524,12 @@ def launch_sweep(
     os.makedirs(workdir, exist_ok=True)
     seeds = [int(s) for s in seeds]
     n_workers = max(1, min(int(n_workers), len(seeds)))
+    if n_workers > 1 and tpu_expected():
+        raise ValueError(
+            f"n_workers={n_workers} on a host with {tpu_chips_on_host()} TPU "
+            f"chip(s): a worker process opens every chip of its host, so a "
+            f"second one would find none. Use n_workers=1 per TPU host (or "
+            f"JAX_PLATFORMS=cpu for CPU workers).")
     shards = slice_seed_shards(seeds, n_shards if n_shards else n_workers)
     n_shards = len(shards)
 

@@ -31,6 +31,9 @@ Robustness wiring (all no-ops outside a supervised launch):
   worker by staleness (it is a PROGRESS beat: the launcher only treats a
   worker as stalled once it has beaten at least once this attempt, so
   import/compile startup never reads as a stall);
+* the worker turns on the compile cache and exits with
+  ``launch.device.EXIT_NO_DEVICE`` when the host has a TPU it could not
+  open, instead of running on the CPU;
 * **chaos hooks** (``streaming/chaos.py``) are installed from the
   ``REPRO_CHAOS_PLAN`` env var — production code carries no fault-injection
   branches;
@@ -231,7 +234,16 @@ def main(argv=None) -> int:
     with open(args.spec) as f:
         spec = json.load(f)
 
+    from repro.launch.device import (EXIT_NO_DEVICE, init_compile_cache,
+                                     require_expected_platform)
     from repro.obs import install
+
+    init_compile_cache()
+    try:
+        require_expected_platform()
+    except RuntimeError as e:
+        print(f"worker: {e}", file=sys.stderr)
+        return EXIT_NO_DEVICE
 
     if args.fleet:
         from repro.streaming.fleet import fleet_worker_loop

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..configs.base import ModelConfig, PSAConfig
-from ..core.compat import LEGACY_SHARD_MAP, shard_map
 from ..models import sharding as shd
 from ..models.transformer import decode_step, forward
 from ..optim.adamw import AdamWConfig, adamw_init, adamw_update
@@ -94,19 +93,14 @@ def make_psa_train_step(cfg: ModelConfig, mesh: Mesh, opt: AdamWConfig,
     n_pods = mesh.shape[pod_axis]
     # inside the shard_map body "pod" is manual — constraints may only name
     # the auto axes, and the batch is the per-pod shard
-    # legacy shard_map: constraints naming auto axes inside the partial-auto
-    # region CHECK-crash the old partitioner — drop the (perf-only) hints
-    aspecs = None if LEGACY_SHARD_MAP else shd.activation_specs(
+    aspecs = shd.activation_specs(
         cfg, mesh, max(global_batch // n_pods, 1), dp=("data",))
     from ..models.transformer import embed_inputs
 
     def local_loss(p, x, labels):
         batch = {"inputs_embeds": x, "labels": labels}
-        # legacy shard_map also CHECK-crashes on lax.scan over a replicated
-        # xs (the layer-group stack) with a pod-sharded carry inside the
-        # partial-auto region — unroll the group loop there (same math)
         return loss_fn(p, batch, cfg, use_pallas=use_pallas, remat=remat,
-                       unroll_layers=LEGACY_SHARD_MAP, act_specs=aspecs)
+                       act_specs=aspecs)
 
     def inner_grads(params, psa_state, x, labels):
         """shard_map body: per-pod grads -> PSA-reduced grads + x cotangent."""
@@ -139,17 +133,12 @@ def make_psa_train_step(cfg: ModelConfig, mesh: Mesh, opt: AdamWConfig,
     lbl_pod = P(pod_axis, *lbl_spec[1:]) if lbl_spec[0] is not None else lbl_spec
     x_pod = P(pod_axis if lbl_spec[0] is not None else None, None, None)
 
-    inner_sm = shard_map(
+    inner_sm = jax.shard_map(
         inner_grads, mesh=mesh, axis_names={pod_axis}, check_vma=False,
         in_specs=(rep, rep, x_pod, lbl_pod),
         out_specs=(rep, rep, rep, x_pod))
-    # refresh gossips with ppermute, which the legacy partial-auto partitioner
-    # cannot lower (only psum survives there) — run the refresh body fully
-    # manual on legacy jax: redundant compute over the auto axes, identical
-    # math (refresh is rare: one S-DOT subspace update every refresh period)
-    refresh_axes = set(mesh.axis_names) if LEGACY_SHARD_MAP else {pod_axis}
-    refresh_sm = shard_map(
-        inner_refresh, mesh=mesh, axis_names=refresh_axes, check_vma=False,
+    refresh_sm = jax.shard_map(
+        inner_refresh, mesh=mesh, axis_names={pod_axis}, check_vma=False,
         in_specs=(rep, rep, x_pod, lbl_pod),
         out_specs=rep)
 

@@ -107,7 +107,6 @@ def test_two_level_reduce_exactness():
     run_spmd("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.core.compat import shard_map
         from repro.core.topology import ring
         from repro.core.consensus import SpmdConsensus, two_level_reduce
         devs = np.array(jax.devices()).reshape(4, 2)
@@ -118,7 +117,7 @@ def test_two_level_reduce_exactness():
         def f(zloc):
             return two_level_reduce(zloc[0, 0], intra_axis="data",
                                     inter=spmd, t_c=60)[None, None]
-        out = jax.jit(shard_map(f, mesh=mesh,
+        out = jax.jit(jax.shard_map(f, mesh=mesh,
                                 in_specs=(P("pod", "data", None, None),),
                                 out_specs=P("pod", "data", None, None)))(z)
         want = z.sum(axis=(0, 1))
