@@ -41,6 +41,7 @@ __all__ = [
     "debias_weights",
     "debias_table",
     "debiased_gossip",
+    "debias_by_row",
     "gossip_mix",
     "masked_gossip",
     "realized_round_weights",
@@ -186,10 +187,16 @@ def debiased_gossip(w: jnp.ndarray, table: jnp.ndarray, z_stack: jnp.ndarray,
     table[t_c]. Free function so one jit cache serves every engine with the
     same shapes.
     """
-    out = masked_gossip(w, z_stack, t_c, t_max)
+    return debias_by_row(table, masked_gossip(w, z_stack, t_c, t_max), t_c)
+
+
+def debias_by_row(table: jnp.ndarray, z_stack: jnp.ndarray,
+                  t_c: jnp.ndarray) -> jnp.ndarray:
+    """Divide each node's block by its weight in the debias table's row
+    ``t_c`` (traceable; ``debiased_gossip``'s second half)."""
     scale = table[t_c]                                       # (N,)
     bshape = (-1,) + (1,) * (z_stack.ndim - 1)
-    return out / scale.astype(out.dtype).reshape(bshape)
+    return z_stack / scale.astype(z_stack.dtype).reshape(bshape)
 
 
 def debias_weights(w: np.ndarray, t_c: int) -> np.ndarray:
@@ -287,8 +294,6 @@ class DenseConsensus:
                              "sparse mixing path")
         else:
             self._w = jnp.asarray(self.weights)
-            from ..obs import metrics
-            metrics().counter("gossip_kernel_dense_total").inc()
         self._debias_tables = {}  # t_max -> (t_max+1, N) device table
 
     @property

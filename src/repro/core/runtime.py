@@ -52,6 +52,12 @@ The jitted chunk program is shared by ALL of the above: its cache key is
 chunked run of the same Program reuse one compiled program per distinct
 chunk length, and repeated runs across Program instances with equal
 configuration recompile nothing.
+
+Every run is a few host spans on the profiler's clock
+(``obs.trace_span``): ``runtime.init`` (the RunState), one
+``runtime.dispatch`` per chunk (its ``jit_miss`` is 1 when the chunk
+program's jit cache grew), ``runtime.sync`` (``int(state.step)``, the
+run's one wait for the device) and ``runtime.finalize``.
 """
 from __future__ import annotations
 
@@ -66,7 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..checkpoint.manager import CheckpointManager
-from ..obs import get_journal
+from ..obs import get_journal, trace_span
 from .metrics import CommLedger
 
 __all__ = ["RunState", "Program", "sync_body", "run_monolithic",
@@ -279,7 +285,8 @@ def _drive_chunks(state: RunState, program: Program, chunk_size: int,
     # Out-of-band tracing: journal writes are host-side appends with no
     # device sync, so the dispatch pipelining above is preserved. Per-chunk
     # "dispatch_s" is enqueue time only; a jit-cache-size delta separates
-    # compile chunks from steady-state ones.
+    # compile chunks from steady-state ones, in the journal and as the
+    # profiler span's ``jit_miss``.
     j = get_journal()
     step0, t_start = step, time.monotonic()
     while step < t_outer:
@@ -290,19 +297,21 @@ def _drive_chunks(state: RunState, program: Program, chunk_size: int,
         length = min(chunk_size, t_outer - step)
         if target_step is not None:
             length = min(length, target_step - step)
-        xs_chunk = jnp.asarray(program.xs[..., step:step + length],
-                               jnp.int32)
-        if j.enabled:
-            n_compiled, t0 = _chunk_program._cache_size(), time.monotonic()
-        state = _chunk_program(state, program.operands, xs_chunk,
-                               build=program.build_body,
-                               statics=program.statics,
-                               case_axes=case_axes, seeded=seeded)
+        n_compiled, t0 = _chunk_program._cache_size(), time.monotonic()
+        with trace_span("runtime.dispatch") as span:
+            xs_chunk = jnp.asarray(program.xs[..., step:step + length],
+                                   jnp.int32)
+            state = _chunk_program(state, program.operands, xs_chunk,
+                                   build=program.build_body,
+                                   statics=program.statics,
+                                   case_axes=case_axes, seeded=seeded)
+            compiled = _chunk_program._cache_size() > n_compiled
+            span.count(jit_miss=int(compiled))
         step += length
         if j.enabled:
             j.event("chunk", phase="runtime", step=step, length=length,
                     dispatch_s=round(time.monotonic() - t0, 6),
-                    compiled=_chunk_program._cache_size() > n_compiled)
+                    compiled=compiled)
         if manager is not None:
             manager.save(step, state, blocking=False)
         done += 1
@@ -320,18 +329,21 @@ def _drive_chunks(state: RunState, program: Program, chunk_size: int,
 def _run(program: Program, manager: Optional[CheckpointManager],
          chunk_size: int, max_chunks: Optional[int],
          target_step: Optional[int] = None):
-    like = _init_state(program)
-    restored = _restore_any(manager, like)
+    with trace_span("runtime.init"):
+        like = _init_state(program)
+        restored = _restore_any(manager, like)
     # the step the run ACTUALLY resumed from (a corrupt/stale newest
     # checkpoint falls back, so this can differ from manager.latest_step())
     program.restored_step = int(restored.step) if restored is not None else 0
     state = restored if restored is not None else like
     state = _drive_chunks(state, program, chunk_size, manager, max_chunks,
                           target_step)
-    done = int(state.step)
+    with trace_span("runtime.sync"):
+        done = int(state.step)
     if program.finalize is None:
         return state
-    return program.finalize(state, done)
+    with trace_span("runtime.finalize"):
+        return program.finalize(state, done)
 
 
 # ---------------------------------------------------------------------------

@@ -47,13 +47,14 @@ from jax.sharding import PartitionSpec as P
 
 from . import runtime
 from .async_gossip import masked_async_rounds
-from .consensus import (DenseConsensus, consensus_schedule, debias_table,
-                        debiased_gossip)
+from .consensus import (DenseConsensus, consensus_schedule, debias_by_row,
+                        debias_table, masked_gossip)
 from .netfaults import (masked_faulty_rounds, realized_debias,
                         sample_fault_blocks)
 from .linalg import PRECISION, cholesky_qr2, orthonormal_init
 from .metrics import CommLedger, mean_subspace_error, subspace_error
 from ..kernels import ops as kops
+from ..obs import trace_span
 
 __all__ = ["SDOTResult", "sdot", "sadot", "sdot_program", "sdot_spmd",
            "local_cov_apply"]
@@ -105,6 +106,15 @@ def _apply_operand(operand, mode: str, q_nodes):
     return kops.batched_gram_apply(x_stack, q_nodes, n_true)
 
 
+def debiased_gossip(w, table, z_stack, t_c, t_max: int):
+    """``consensus.debiased_gossip`` with its two halves under S-DOT's
+    step scopes, ``sdot.gossip`` and ``sdot.debias``."""
+    with jax.named_scope("sdot.gossip"):
+        z = masked_gossip(w, z_stack, t_c, t_max)
+    with jax.named_scope("sdot.debias"):
+        return debias_by_row(table, z, t_c)
+
+
 def _sync_outer_body(operand, w, table, q_true, node_mask, *, mode: str,
                      t_max: int, trace_err: bool):
     """Build the per-outer-iteration body ``(q_nodes, t_c) -> (q_new, err)``.
@@ -113,14 +123,22 @@ def _sync_outer_body(operand, w, table, q_true, node_mask, *, mode: str,
     via ``_sdot_build_body``), so a run split at arbitrary chunk boundaries
     replays the monolithic scan bit for bit — the math cannot drift between
     the callers.
+
+    Each step of Alg. 1 runs under a ``jax.named_scope`` (``sdot.apply``,
+    ``sdot.gossip``, ``sdot.debias``, ``sdot.qr``, ``sdot.error``), the same
+    names in every outer body, so a profiler trace attributes each device
+    op to its step; scopes change op metadata only, not the arithmetic.
     """
 
     def outer(q_nodes, t_c):
-        z0 = _apply_operand(operand, mode, q_nodes)              # (N, d, r)
+        with jax.named_scope("sdot.apply"):
+            z0 = _apply_operand(operand, mode, q_nodes)          # (N, d, r)
         v = debiased_gossip(w, table, z0, t_c, t_max)
-        q_new = jax.vmap(lambda vv: cholesky_qr2(vv)[0])(v)      # per-node QR
-        err = (mean_subspace_error(q_true, q_new, node_mask) if trace_err
-               else jnp.float32(0.0))
+        with jax.named_scope("sdot.qr"):
+            q_new = jax.vmap(lambda vv: cholesky_qr2(vv)[0])(v)  # per-node QR
+        with jax.named_scope("sdot.error"):
+            err = (mean_subspace_error(q_true, q_new, node_mask)
+                   if trace_err else jnp.float32(0.0))
         return q_new, err
 
     return outer
@@ -133,19 +151,26 @@ def _async_outer_body(operand, w, adj, p_awake, q_true, *, mode: str,
     Each call splits the key, draws the iteration's (t_max, N) awake-mask
     block, and runs realized-matrix gossip — the key ride in the carry is
     exactly what makes chunked resume exact for straggler runs: checkpointing
-    the carried key restores the stream mid-run with no replay.
+    the carried key restores the stream mid-run with no replay. Scopes as
+    in ``_sync_outer_body``; the realized debias is part of the realized
+    rounds, so it runs under ``sdot.gossip``.
     """
     n = w.shape[0]
 
     def outer(carry, t_c):
         q_nodes, key = carry
-        key, sub = jax.random.split(key)
-        awake = jax.random.bernoulli(sub, p_awake, (t_max, n))
-        z0 = _apply_operand(operand, mode, q_nodes)              # (N, d, r)
-        v, sends, counts = masked_async_rounds(w, adj, awake, t_c, z0)
-        q_new = jax.vmap(lambda vv: cholesky_qr2(vv)[0])(v)
-        err = (mean_subspace_error(q_true, q_new) if trace_err
-               else jnp.float32(0.0))
+        with jax.named_scope("sdot.gossip"):
+            key, sub = jax.random.split(key)
+            awake = jax.random.bernoulli(sub, p_awake, (t_max, n))
+        with jax.named_scope("sdot.apply"):
+            z0 = _apply_operand(operand, mode, q_nodes)          # (N, d, r)
+        with jax.named_scope("sdot.gossip"):
+            v, sends, counts = masked_async_rounds(w, adj, awake, t_c, z0)
+        with jax.named_scope("sdot.qr"):
+            q_new = jax.vmap(lambda vv: cholesky_qr2(vv)[0])(v)
+        with jax.named_scope("sdot.error"):
+            err = (mean_subspace_error(q_true, q_new) if trace_err
+                   else jnp.float32(0.0))
         return (q_new, key), (err, sends, counts)
 
     return outer
@@ -167,28 +192,36 @@ def _faulty_outer_body(operand, w, adj, params, node_up_sched, table,
     (the QR update is masked), so on rejoin they re-sync from neighbors
     through ordinary gossip. ``debias``: "realized" divides by the carried
     realized mixing product (self-healing); "nominal" divides by the
-    fault-free W^t e_1 table row (the uncorrected benchmark arm).
+    fault-free W^t e_1 table row (the uncorrected benchmark arm). Scopes
+    as in ``_sync_outer_body``.
     """
     n = w.shape[0]
 
     def outer(carry, t_c):
         (q_nodes, ge, t), key = carry
-        key, sub = jax.random.split(key)
-        blocks = sample_fault_blocks(sub, n, t_max)
-        node_up = jnp.take(node_up_sched, t, axis=0)             # (N,)
-        z0 = _apply_operand(operand, mode, q_nodes)              # (N, d, r)
-        z, p, ge_new, sends, counts = masked_faulty_rounds(
-            w, adj, params, node_up, ge, blocks, t_c, z0)
-        if debias == "realized":
-            v = realized_debias(z, p)
-        else:
-            row = jnp.take(table, t_c, axis=0)
-            v = z / row.astype(z.dtype).reshape((-1,) + (1,) * (z.ndim - 1))
-        q_qr = jax.vmap(lambda vv: cholesky_qr2(vv)[0])(v)
-        up = node_up.reshape((-1,) + (1,) * (q_nodes.ndim - 1)) > 0
-        q_new = jnp.where(up, q_qr, q_nodes)                     # freeze
-        err = (mean_subspace_error(q_true, q_new) if trace_err
-               else jnp.float32(0.0))
+        with jax.named_scope("sdot.gossip"):
+            key, sub = jax.random.split(key)
+            blocks = sample_fault_blocks(sub, n, t_max)
+            node_up = jnp.take(node_up_sched, t, axis=0)         # (N,)
+        with jax.named_scope("sdot.apply"):
+            z0 = _apply_operand(operand, mode, q_nodes)          # (N, d, r)
+        with jax.named_scope("sdot.gossip"):
+            z, p, ge_new, sends, counts = masked_faulty_rounds(
+                w, adj, params, node_up, ge, blocks, t_c, z0)
+        with jax.named_scope("sdot.debias"):
+            if debias == "realized":
+                v = realized_debias(z, p)
+            else:
+                row = jnp.take(table, t_c, axis=0)
+                v = z / row.astype(z.dtype).reshape(
+                    (-1,) + (1,) * (z.ndim - 1))
+        with jax.named_scope("sdot.qr"):
+            q_qr = jax.vmap(lambda vv: cholesky_qr2(vv)[0])(v)
+            up = node_up.reshape((-1,) + (1,) * (q_nodes.ndim - 1)) > 0
+            q_new = jnp.where(up, q_qr, q_nodes)                 # freeze
+        with jax.named_scope("sdot.error"):
+            err = (mean_subspace_error(q_true, q_new) if trace_err
+                   else jnp.float32(0.0))
         return ((q_new, ge_new, t + 1), key), (err, sends, counts)
 
     return outer
@@ -240,9 +273,10 @@ def sdot_program(
     values. ``runtime.run_monolithic`` reproduces ``sdot(fused=True)``;
     ``runtime.run_chunked`` is the restartable twin (streaming/resume.py).
     """
-    prep = _prepare_sdot(covs=covs, data=data, engine=engine, r=r,
-                         t_outer=t_outer, schedule=schedule, t_c=t_c,
-                         q_init=q_init, q_true=q_true, seed=seed)
+    with trace_span("sdot.prepare"):
+        prep = _prepare_sdot(covs=covs, data=data, engine=engine, r=r,
+                             t_outer=t_outer, schedule=schedule, t_c=t_c,
+                             q_init=q_init, q_true=q_true, seed=seed)
     n, d = prep["n"], prep["d"]
     t_max, trace_err, q_arg = prep["t_max"], prep["trace_err"], prep["q_arg"]
     sched_np = prep["sched_np"]
@@ -389,6 +423,12 @@ def sdot(
     ``fused=True`` (default) executes the whole run as a single compiled
     scan (a thin shim over ``runtime.run_monolithic``); ``fused=False`` is
     the eager per-iteration oracle.
+
+    The fused run is one ``sdot.solve`` host span on the profiler's clock
+    (``obs.trace_span``) whose counts are the gossip rounds the masked scan
+    runs (``rounds_run``, t_outer * t_max) and those the schedule asks for
+    (``rounds_needed``); building the Program is ``sdot.program`` (with
+    ``sdot.prepare`` in it), and the runtime adds its own spans.
     """
     # async / faulty engines get their own whole-run scan (the RNG key —
     # and for faults the Gilbert–Elliott state — rides in the carry); any
@@ -396,10 +436,16 @@ def sdot(
     if fused and (hasattr(engine, "sample_awake")
                   or hasattr(engine, "sample_faults")
                   or hasattr(engine, "debias_table")):
-        return runtime.run_monolithic(sdot_program(
-            covs=covs, data=data, engine=engine, r=r, t_outer=t_outer,
-            schedule=schedule, t_c=t_c, q_init=q_init, q_true=q_true,
-            seed=seed))
+        with trace_span("sdot.solve") as span:
+            with trace_span("sdot.program"):
+                program = sdot_program(
+                    covs=covs, data=data, engine=engine, r=r,
+                    t_outer=t_outer, schedule=schedule, t_c=t_c,
+                    q_init=q_init, q_true=q_true, seed=seed)
+            span.count(
+                rounds_run=program.t_outer * dict(program.statics)["t_max"],
+                rounds_needed=int(program.xs.sum()))
+            return runtime.run_monolithic(program)
 
     prep = _prepare_sdot(covs=covs, data=data, engine=engine, r=r,
                          t_outer=t_outer, schedule=schedule, t_c=t_c,
@@ -484,51 +530,70 @@ def sdot_spmd(
     and one dispatch per run instead of one collective chain per outer
     iteration; numerically identical to the fused ``DenseConsensus`` run
     for the same W (tests/test_spmd.py pins it).
+
+    Host spans as in ``sdot``: ``sdot_spmd.solve`` (with ``rounds_run`` and
+    ``rounds_needed``) holds ``sdot_spmd.prepare`` and ``sdot_spmd.call``,
+    the fresh ``jax.jit`` traced and dispatched on every call
+    (``jit_miss=1``).
     """
     n = engine.n
     if covs.shape[0] != n:
         raise ValueError("covs leading dim must equal the mesh axis size")
-    d = covs.shape[1]
-    if schedule is None:
-        schedule = consensus_schedule("const", t_outer, t_max=t_c)
-    elif len(schedule) < t_outer:
-        raise ValueError(f"schedule has {len(schedule)} entries but "
-                         f"t_outer={t_outer}")
-    sched_np = np.asarray(schedule[:t_outer])
-    t_max = int(sched_np.max()) if t_outer else 0
-    if q_init is None:
-        q_init = orthonormal_init(jax.random.PRNGKey(seed), d, r)
-    q_nodes = jnp.broadcast_to(q_init[None], (n, d, r))
-    trace_err = q_true is not None
-    q_arg = q_true if trace_err else jnp.zeros((d, r), jnp.float32)
-    table = engine.debias_table(t_max)
-    sched_dev = jnp.asarray(sched_np, jnp.int32)
+    with trace_span("sdot_spmd.solve") as span:
+        with trace_span("sdot_spmd.prepare"):
+            d = covs.shape[1]
+            if schedule is None:
+                schedule = consensus_schedule("const", t_outer, t_max=t_c)
+            elif len(schedule) < t_outer:
+                raise ValueError(f"schedule has {len(schedule)} entries but "
+                                 f"t_outer={t_outer}")
+            sched_np = np.asarray(schedule[:t_outer])
+            t_max = int(sched_np.max()) if t_outer else 0
+            if q_init is None:
+                q_init = orthonormal_init(jax.random.PRNGKey(seed), d, r)
+            q_nodes = jnp.broadcast_to(q_init[None], (n, d, r))
+            trace_err = q_true is not None
+            q_arg = q_true if trace_err else jnp.zeros((d, r), jnp.float32)
+            table = engine.debias_table(t_max)
+            sched_dev = jnp.asarray(sched_np, jnp.int32)
+        span.count(rounds_run=t_outer * t_max,
+                   rounds_needed=int(sched_np.sum()))
 
-    def local_fn(cov, q0, sched, tab, qt):
-        # cov/q0: (1, d, d) / (1, d, r) local blocks; sched/tab/qt replicated
-        def outer(q, tc):
-            z = jnp.matmul(cov[0], q, precision=PRECISION)
-            z = engine.gossip_rounds_masked(z, tc, t_max)
-            z = engine.debias_by_table(z, tab, tc)
-            q_new = cholesky_qr2(z)[0]
-            err = (jax.lax.pmean(subspace_error(qt, q_new), engine.axis)
-                   if trace_err else jnp.float32(0.0))
-            return q_new, err
+        def local_fn(cov, q0, sched, tab, qt):
+            # cov/q0: (1, d, d) / (1, d, r) local blocks; sched/tab/qt
+            # replicated; scopes as in _sync_outer_body
+            def outer(q, tc):
+                with jax.named_scope("sdot.apply"):
+                    z = jnp.matmul(cov[0], q, precision=PRECISION)
+                with jax.named_scope("sdot.gossip"):
+                    z = engine.gossip_rounds_masked(z, tc, t_max)
+                with jax.named_scope("sdot.debias"):
+                    z = engine.debias_by_table(z, tab, tc)
+                with jax.named_scope("sdot.qr"):
+                    q_new = cholesky_qr2(z)[0]
+                with jax.named_scope("sdot.error"):
+                    err = (jax.lax.pmean(subspace_error(qt, q_new),
+                                         engine.axis)
+                           if trace_err else jnp.float32(0.0))
+                return q_new, err
 
-        qf, errs = jax.lax.scan(outer, q0[0], sched)
-        return qf[None], errs
+            qf, errs = jax.lax.scan(outer, q0[0], sched)
+            return qf[None], errs
 
-    spec, rep = P(engine.axis), P()
-    fn = jax.shard_map(local_fn, mesh=engine.mesh,
-                       in_specs=(spec, spec, rep, rep, rep),
-                       out_specs=(spec, rep))
-    q_nodes, errs = jax.jit(fn)(covs, q_nodes, sched_dev, table, q_arg)
+        # the jit is made anew on every call, so each call traces again
+        with trace_span("sdot_spmd.call", jit_miss=1):
+            spec, rep = P(engine.axis), P()
+            fn = jax.shard_map(local_fn, mesh=engine.mesh,
+                               in_specs=(spec, spec, rep, rep, rep),
+                               out_specs=(spec, rep))
+            q_nodes, errs = jax.jit(fn)(covs, q_nodes, sched_dev, table,
+                                        q_arg)
 
-    ledger = CommLedger()
-    ledger.log_gossip_rounds(sched_np, engine.graph.adjacency, d * r)
-    return SDOTResult(
-        q_nodes=q_nodes,
-        error_trace=np.asarray(errs) if trace_err else None,
-        consensus_trace=sched_np,
-        ledger=ledger,
-    )
+        ledger = CommLedger()
+        ledger.log_gossip_rounds(sched_np, engine.graph.adjacency, d * r)
+        return SDOTResult(
+            q_nodes=q_nodes,
+            error_trace=np.asarray(errs) if trace_err else None,
+            consensus_trace=sched_np,
+            ledger=ledger,
+        )

@@ -1,7 +1,16 @@
-"""Unified observability layer: span journal + metrics registry + CLI.
+"""Unified observability layer: profiler spans, span journal, metrics
+registry, CLI.
 
-Three pieces, strictly OUT-OF-BAND (host-side file appends only — device
-math replays bit-identical with tracing on or off):
+The in-process layer is ``trace_span``: a host span (with integer counts
+as its arguments) on the profiler's own clock, so that a ``jax.profiler``
+trace holds the program's host phases beside the device's ops. It is what
+the hot path uses (``core/sdot``, ``core/runtime``): a handful of spans a
+solve, about a microsecond each when no profiler runs; nothing goes to a
+file, and their counts add up in the registry below. The journal keeps its
+own clock (``time.monotonic``) and never sees these spans.
+
+Three more pieces, strictly OUT-OF-BAND (host-side file appends only —
+device math replays bit-identical with tracing on or off):
 
 * ``journal`` — crash-safe append-only JSONL span/event journals, one per
   process attempt, with a torn-tail-tolerant reader;
@@ -35,10 +44,59 @@ from .registry import Counter, Gauge, Histogram, MetricsRegistry
 __all__ = ["Journal", "Span", "read_journal", "merge_journals",
            "journal_files", "Counter", "Gauge", "Histogram",
            "MetricsRegistry", "get_journal", "set_journal", "metrics",
-           "install", "obs_dir_for", "ENV_DIR", "ENV_OBS"]
+           "install", "obs_dir_for", "trace_span", "ENV_DIR", "ENV_OBS"]
 
 _journal: Journal = Journal.noop()
 _registry: MetricsRegistry = MetricsRegistry()
+
+
+class _TraceSpan:
+    """What ``trace_span`` returns; see there."""
+
+    __slots__ = ("_name", "_note")
+
+    def __init__(self, name: str, counts: dict):
+        from jax.profiler import TraceAnnotation
+
+        self._name = name
+        self._note = TraceAnnotation(name, **counts)
+        if counts:
+            self._add(counts)
+
+    def __enter__(self) -> "_TraceSpan":
+        self._note.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._note.__exit__(*exc)
+
+    def count(self, **counts) -> None:
+        """Counts known only at the span's end, recorded as if given when
+        it was opened."""
+        self._note.set_metadata(**counts)
+        self._add(counts)
+
+    def _add(self, counts: dict) -> None:
+        stem = self._name.replace(".", "_")
+        _registry.counter(f"{stem}_total").inc()
+        for key, value in counts.items():
+            _registry.counter(f"{stem}_{key}_total").inc(value)
+
+
+def trace_span(name: str, **counts) -> _TraceSpan:
+    """A host span named ``name`` on the profiler's clock: a context
+    manager over ``jax.profiler.TraceAnnotation``, so a running
+    ``jax.profiler`` trace holds it beside the device's ops, with the
+    integer ``counts`` as the span's arguments (its event stats). With no
+    profiler running it records nothing in any trace.
+
+    Counts also add up in the process registry (``metrics()``), where an
+    in-process reader finds them without a trace: each recording of counts
+    (when the span is made, or a ``count(**counts)`` call on the entered
+    span for counts known only at its end) adds one to ``<name>_total`` and
+    each count to ``<name>_<count>_total`` (dots in ``name`` become ``_``).
+    """
+    return _TraceSpan(name, counts)
 
 
 def get_journal() -> Journal:

@@ -1,0 +1,196 @@
+"""The program's own measurement: host spans on the profiler's clock with
+their counts, the counts in the process registry, and the step scopes in
+the compiled program's op metadata."""
+import glob
+import json
+import os
+import pathlib
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro import obs
+from repro.core import runtime
+from repro.core import sdot as sdot_mod
+from repro.core.async_gossip import AsyncConsensus
+from repro.core.consensus import DenseConsensus
+from repro.core.netfaults import FaultyConsensus, NetFaultModel
+from repro.core.topology import erdos_renyi
+from repro.obs.registry import MetricsRegistry
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+N, D, R = 5, 12, 2
+SDOT_SPANS = {"sdot.solve", "sdot.program", "sdot.prepare", "runtime.init",
+              "runtime.dispatch", "runtime.sync", "runtime.finalize"}
+SPMD_SPANS = {"sdot_spmd.solve", "sdot_spmd.prepare", "sdot_spmd.call"}
+SCOPES = ("sdot.apply", "sdot.gossip", "sdot.debias", "sdot.qr")
+
+
+@pytest.fixture
+def registry(monkeypatch):
+    reg = MetricsRegistry()
+    monkeypatch.setattr(obs, "_registry", reg)
+    return reg
+
+
+def _covs(seed=0):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(np.stack([np.cov(rng.normal(size=(D, 40)))
+                                 for _ in range(N)]), jnp.float32)
+
+
+def _host_spans(trace_dir):
+    """(name, stats) of every host event in the trace under
+    ``trace_dir`` whose name is a program span."""
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    return [(e.name, dict(e.stats))
+            for p in ProfileData.from_file(path).planes
+            if not p.name.startswith("/device:")
+            for ln in p.lines for e in ln.events
+            if e.name.startswith(("sdot.", "sdot_spmd.", "runtime."))]
+
+
+def test_span_counts_go_to_trace_and_registry(tmp_path, registry):
+    jax.profiler.start_trace(str(tmp_path))
+    with obs.trace_span("a.b", hits=2):
+        pass
+    with obs.trace_span("a.c") as span:
+        span.count(hits=3)
+    with obs.trace_span("a.d"):
+        pass
+    jax.profiler.stop_trace()
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    stats = {e.name: dict(e.stats)
+             for p in ProfileData.from_file(path).planes
+             for ln in p.lines for e in ln.events if e.name.startswith("a.")}
+    assert stats == {"a.b": {"hits": 2}, "a.c": {"hits": 3}, "a.d": {}}
+    assert {k: v["value"] for k, v in registry.snapshot().items()} == {
+        "a_b_total": 1, "a_b_hits_total": 2,
+        "a_c_total": 1, "a_c_hits_total": 3}
+
+
+def test_traced_sdot_writes_its_spans_and_counts(tmp_path, registry):
+    eng = DenseConsensus(erdos_renyi(N, 0.6, seed=1))
+    sched = np.array([1, 2, 3, 4, 4, 4])
+    kw = dict(covs=_covs(), engine=eng, r=R, t_outer=6, schedule=sched)
+    sdot_mod.sdot(**kw).q_nodes.block_until_ready()        # compiles
+    jax.profiler.start_trace(str(tmp_path))
+    sdot_mod.sdot(**kw).q_nodes.block_until_ready()
+    jax.profiler.stop_trace()
+    spans = _host_spans(tmp_path)
+    assert {name for name, _ in spans} == SDOT_SPANS
+    stats = dict(spans)
+    assert stats["sdot.solve"] == {"rounds_run": 24, "rounds_needed": 18}
+    assert stats["runtime.dispatch"] == {"jit_miss": 0}
+    counts = {k: v["value"] for k, v in registry.snapshot().items()}
+    assert counts == {"sdot_solve_total": 2,
+                      "sdot_solve_rounds_run_total": 48,
+                      "sdot_solve_rounds_needed_total": 36,
+                      "runtime_dispatch_total": 2,
+                      "runtime_dispatch_jit_miss_total": 1}
+
+
+def _faulty():
+    return FaultyConsensus(graph=erdos_renyi(N, 0.6, seed=1),
+                           faults=NetFaultModel(p_drop=0.2), seed=0)
+
+
+@pytest.mark.parametrize("engine, operand, scopes", [
+    (lambda: DenseConsensus(erdos_renyi(N, 0.6, seed=1)), "covs", SCOPES),
+    (lambda: DenseConsensus(erdos_renyi(N, 0.6, seed=1)), "data", SCOPES),
+    (lambda: AsyncConsensus(erdos_renyi(N, 0.6, seed=1), p_awake=0.7,
+                            seed=0), "covs",
+     ("sdot.apply", "sdot.gossip", "sdot.qr")),
+    (_faulty, "covs", SCOPES),
+], ids=["dense-cov", "dense-data", "async", "faulty"])
+def test_compiled_program_names_each_step(engine, operand, scopes):
+    if operand == "covs":
+        op = {"covs": _covs()}
+    else:
+        rng = np.random.default_rng(1)
+        op = {"data": [jnp.asarray(rng.normal(size=(D, 20 + i)), jnp.float32)
+                       for i in range(N)]}
+    prog = sdot_mod.sdot_program(**op, engine=engine(), r=R, t_outer=3,
+                                 t_c=4)
+    text = runtime.lower_monolithic(prog).compile().as_text()
+    names = set(re.findall(r'op_name="[^"]*?(sdot\.[a-z]+)', text))
+    assert set(scopes) <= names
+
+
+SPMD_CHILD = r'''
+import glob, json, sys, tempfile
+sys.path.insert(0, sys.argv[1] + "/src")
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh
+from jax.profiler import ProfileData
+from repro import obs
+from repro.core import sdot as sdot_mod
+from repro.core.consensus import SpmdConsensus
+from repro.core.topology import ring
+
+n, d, r = 4, 8, 2
+rng = np.random.default_rng(0)
+covs = jnp.asarray(np.stack([np.cov(rng.normal(size=(d, 30)))
+                             for _ in range(n)]), jnp.float32)
+eng = SpmdConsensus(Mesh(np.array(jax.devices()[:n]), ("node",)), "node",
+                    graph=ring(n))
+kw = dict(engine=eng, r=r, t_outer=4, schedule=np.array([1, 2, 3, 3]))
+texts, jit = [], jax.jit
+
+
+def lowering_jit(fn):       # the program sdot_spmd makes, lowered as run
+    def call(*args):
+        texts.append(jit(fn).lower(*args).as_text(debug_info=True))
+        return jit(fn)(*args)
+    return call
+
+
+jax.jit = lowering_jit
+sdot_mod.sdot_spmd(covs=covs, **kw).q_nodes.block_until_ready()
+jax.jit = jit
+text, = texts
+out = tempfile.mkdtemp()
+jax.profiler.start_trace(out)
+sdot_mod.sdot_spmd(covs=covs, **kw).q_nodes.block_until_ready()
+jax.profiler.stop_trace()
+path, = glob.glob(out + "/**/*.xplane.pb", recursive=True)
+spans = {e.name: dict(e.stats) for p in ProfileData.from_file(path).planes
+         for ln in p.lines for e in ln.events
+         if e.name.startswith("sdot_spmd.")}
+print(json.dumps({"spans": spans,
+                  "scopes": sorted(s for s in ("sdot.apply", "sdot.gossip",
+                                               "sdot.debias", "sdot.qr")
+                                   if s in text),
+                  "counts": {k: v["value"] for k, v in
+                             obs.metrics().snapshot().items()}}))
+'''
+
+
+def test_traced_sdot_spmd_writes_its_spans_and_scopes():
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    proc = subprocess.run([sys.executable, "-c", SPMD_CHILD, str(ROOT)],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert set(out["spans"]) == SPMD_SPANS
+    assert out["spans"]["sdot_spmd.solve"] == {"rounds_run": 12,
+                                               "rounds_needed": 9}
+    assert out["spans"]["sdot_spmd.call"] == {"jit_miss": 1}
+    assert out["scopes"] == sorted(SCOPES)
+    # the lowering above ran sdot_spmd once more, outside the trace
+    assert out["counts"] == {"sdot_spmd_solve_total": 2,
+                             "sdot_spmd_solve_rounds_run_total": 24,
+                             "sdot_spmd_solve_rounds_needed_total": 18,
+                             "sdot_spmd_call_total": 2,
+                             "sdot_spmd_call_jit_miss_total": 2}
