@@ -412,6 +412,7 @@ class SpmdConsensus:
         self._is_ring = self._detect_ring()
         self._w = jnp.asarray(self.weights)
         self._debias_tables = {}  # t_max -> (t_max+1, N) device table
+        self._spmd_programs = {}  # (t_max, trace_err) -> sdot_spmd program
 
     def _detect_ring(self) -> bool:
         return np.array_equal(self.graph.adjacency, ring(self.n).adjacency)

@@ -507,6 +507,47 @@ def sadot(*, schedule_kind: str = "lin2", cap: Optional[int] = None,
     return sdot(t_outer=t_outer, schedule=sched, **kw)
 
 
+def _spmd_program(engine, t_max: int, trace_err: bool):
+    """The jitted whole-run shard_map program of ``sdot_spmd`` for
+    ``engine``, built on first use and kept on the engine, keyed by what
+    it bakes in beside the engine (its mesh, axis, ring coefficients or
+    weight row): the static inner scan length ``t_max`` and whether the
+    pmean'd error is traced. Covariances, iterates, schedule, debias table
+    and ``q_true`` are traced arguments, so jit's own cache keys their
+    shapes and dtypes."""
+    key = (t_max, trace_err)
+    program = engine._spmd_programs.get(key)
+    if program is not None:
+        return program
+
+    def local_fn(cov, q0, sched, tab, qt):
+        # cov/q0: (1, d, d) / (1, d, r) local blocks; sched/tab/qt
+        # replicated; scopes as in _sync_outer_body
+        def outer(q, tc):
+            with jax.named_scope("sdot.apply"):
+                z = jnp.matmul(cov[0], q, precision=PRECISION)
+            with jax.named_scope("sdot.gossip"):
+                z = engine.gossip_rounds_masked(z, tc, t_max)
+            with jax.named_scope("sdot.debias"):
+                z = engine.debias_by_table(z, tab, tc)
+            with jax.named_scope("sdot.qr"):
+                q_new = cholesky_qr2(z)[0]
+            with jax.named_scope("sdot.error"):
+                err = (jax.lax.pmean(subspace_error(qt, q_new), engine.axis)
+                       if trace_err else jnp.float32(0.0))
+            return q_new, err
+
+        qf, errs = jax.lax.scan(outer, q0[0], sched)
+        return qf[None], errs
+
+    spec, rep = P(engine.axis), P()
+    program = jax.jit(jax.shard_map(local_fn, mesh=engine.mesh,
+                                    in_specs=(spec, spec, rep, rep, rep),
+                                    out_specs=(spec, rep)))
+    engine._spmd_programs[key] = program
+    return program
+
+
 def sdot_spmd(
     *,
     covs: jnp.ndarray,
@@ -531,10 +572,14 @@ def sdot_spmd(
     iteration; numerically identical to the fused ``DenseConsensus`` run
     for the same W (tests/test_spmd.py pins it).
 
+    The compiled program is built once per engine and (``t_max``, whether
+    ``q_true`` is given) by ``_spmd_program``; later calls with the same
+    shapes reuse it without tracing again.
+
     Host spans as in ``sdot``: ``sdot_spmd.solve`` (with ``rounds_run`` and
     ``rounds_needed``) holds ``sdot_spmd.prepare`` and ``sdot_spmd.call``,
-    the fresh ``jax.jit`` traced and dispatched on every call
-    (``jit_miss=1``).
+    the program's dispatch, whose ``jit_miss`` counts the calls that missed
+    its jit cache (traced, then compiled or loaded from the compile cache).
     """
     n = engine.n
     if covs.shape[0] != n:
@@ -559,35 +604,11 @@ def sdot_spmd(
         span.count(rounds_run=t_outer * t_max,
                    rounds_needed=int(sched_np.sum()))
 
-        def local_fn(cov, q0, sched, tab, qt):
-            # cov/q0: (1, d, d) / (1, d, r) local blocks; sched/tab/qt
-            # replicated; scopes as in _sync_outer_body
-            def outer(q, tc):
-                with jax.named_scope("sdot.apply"):
-                    z = jnp.matmul(cov[0], q, precision=PRECISION)
-                with jax.named_scope("sdot.gossip"):
-                    z = engine.gossip_rounds_masked(z, tc, t_max)
-                with jax.named_scope("sdot.debias"):
-                    z = engine.debias_by_table(z, tab, tc)
-                with jax.named_scope("sdot.qr"):
-                    q_new = cholesky_qr2(z)[0]
-                with jax.named_scope("sdot.error"):
-                    err = (jax.lax.pmean(subspace_error(qt, q_new),
-                                         engine.axis)
-                           if trace_err else jnp.float32(0.0))
-                return q_new, err
-
-            qf, errs = jax.lax.scan(outer, q0[0], sched)
-            return qf[None], errs
-
-        # the jit is made anew on every call, so each call traces again
-        with trace_span("sdot_spmd.call", jit_miss=1):
-            spec, rep = P(engine.axis), P()
-            fn = jax.shard_map(local_fn, mesh=engine.mesh,
-                               in_specs=(spec, spec, rep, rep, rep),
-                               out_specs=(spec, rep))
-            q_nodes, errs = jax.jit(fn)(covs, q_nodes, sched_dev, table,
-                                        q_arg)
+        with trace_span("sdot_spmd.call") as call:
+            program = _spmd_program(engine, t_max, trace_err)
+            n_compiled = program._cache_size()
+            q_nodes, errs = program(covs, q_nodes, sched_dev, table, q_arg)
+            call.count(jit_miss=int(program._cache_size() > n_compiled))
 
         ledger = CommLedger()
         ledger.log_gossip_rounds(sched_np, engine.graph.adjacency, d * r)

@@ -102,6 +102,97 @@ def test_spmd_fused_sdot_matches_dense_fused():
     """)
 
 
+SPMD_REUSE_SETUP = """
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import Mesh
+    from repro import obs
+    from repro.core.topology import erdos_renyi, ring
+    from repro.core.consensus import DenseConsensus, SpmdConsensus
+    from repro.core.sdot import sdot, sdot_spmd
+    from repro.core.linalg import eigh_topr
+    from repro.data.pipeline import gaussian_eigengap_data, partition_samples
+    n, d, r = 8, 16, 3
+    x, _, _ = gaussian_eigengap_data(d, n * 400, r, 0.7, seed=0)
+    covs = jnp.stack([b @ b.T / b.shape[1] for b in partition_samples(x, n)])
+    _, q_true = eigh_topr(covs.sum(0), r)
+    mesh = Mesh(np.array(jax.devices()), ("nodes",))
+
+    def misses():            # sdot_spmd.call's jit_miss total so far
+        snap = obs.metrics().snapshot()
+        return snap.get("sdot_spmd_call_jit_miss_total", {"value": 0})["value"]
+
+    def check(got, g, **kw):  # == the fused DenseConsensus run
+        want = sdot(covs=covs, engine=DenseConsensus(g), r=r, **kw)
+        np.testing.assert_allclose(np.asarray(got.q_nodes),
+                                   np.asarray(want.q_nodes), rtol=1e-4,
+                                   atol=1e-5)
+        if kw.get("q_true") is not None:
+            np.testing.assert_allclose(got.error_trace, want.error_trace,
+                                       rtol=1e-4, atol=1e-6)
+"""
+
+
+def test_sdot_spmd_reuses_its_program_on_one_engine():
+    """A second call on the same engine traces nothing, misses no jit
+    cache and gives bitwise the same nodes as the first."""
+    run_spmd(SPMD_REUSE_SETUP + """
+    traces = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, secs, **kw: traces.append(name)
+        if name == "/jax/core/compile/jaxpr_trace_duration" else None)
+    eng = SpmdConsensus(mesh, "nodes", graph=ring(n))
+    kw = dict(engine=eng, r=r, t_outer=6, schedule=np.array([1, 2, 4, 4, 3, 4]))
+    first = sdot_spmd(covs=covs, **kw).q_nodes.block_until_ready()
+    n_traced, n_missed = len(traces), misses()
+    assert n_traced >= 1 and n_missed == 1, (n_traced, n_missed)
+    second = sdot_spmd(covs=covs, **kw).q_nodes.block_until_ready()
+    assert len(traces) == n_traced, traces[n_traced:]
+    assert misses() == 1
+    assert len(eng._spmd_programs) == 1
+    np.testing.assert_array_equal(np.asarray(second), np.asarray(first))
+    print("reuse OK")
+    """)
+
+
+def test_sdot_spmd_program_is_per_engine():
+    """A second engine on the same mesh with another graph builds its own
+    program, and it still matches the fused DenseConsensus run."""
+    run_spmd(SPMD_REUSE_SETUP + """
+    kw = dict(r=r, t_outer=8, t_c=5)
+    g1, g2 = ring(n), erdos_renyi(n, 0.5, seed=3)
+    e1, e2 = SpmdConsensus(mesh, "nodes", graph=g1), SpmdConsensus(
+        mesh, "nodes", graph=g2)
+    check(sdot_spmd(covs=covs, engine=e1, **kw), g1, t_outer=8, t_c=5)
+    check(sdot_spmd(covs=covs, engine=e2, **kw), g2, t_outer=8, t_c=5)
+    assert misses() == 2
+    (p1,), (p2,) = e1._spmd_programs.values(), e2._spmd_programs.values()
+    assert p1 is not p2
+    print("per engine OK")
+    """)
+
+
+def test_sdot_spmd_program_keyed_by_t_max_and_error_trace():
+    """Another largest T_c, or q_true given against not given, builds a
+    program of its own; a schedule of other values with the same largest
+    T_c reuses one. Every call gives the fused DenseConsensus answer."""
+    run_spmd(SPMD_REUSE_SETUP + """
+    g = ring(n)
+    eng = SpmdConsensus(mesh, "nodes", graph=g)
+    cases = [(np.array([1, 3, 2, 3, 3]), None, 1),
+             (np.array([2, 5, 5, 1, 4]), None, 1),
+             (np.array([1, 3, 2, 3, 3]), q_true, 1),
+             (np.array([3, 1, 1, 2, 3]), None, 0)]   # same largest T_c
+    for sched, qt, missed in cases:
+        before = misses()
+        got = sdot_spmd(covs=covs, engine=eng, r=r, t_outer=5,
+                        schedule=sched, q_true=qt)
+        assert misses() - before == missed, (sched, qt is None)
+        check(got, g, t_outer=5, schedule=sched, q_true=qt)
+    assert sorted(eng._spmd_programs) == [(3, False), (3, True), (5, False)]
+    print("keys OK")
+    """)
+
+
 def test_two_level_reduce_exactness():
     """psum intra + enough gossip rounds inter == the true global sum."""
     run_spmd("""

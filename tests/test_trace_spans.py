@@ -144,20 +144,12 @@ covs = jnp.asarray(np.stack([np.cov(rng.normal(size=(d, 30)))
 eng = SpmdConsensus(Mesh(np.array(jax.devices()[:n]), ("node",)), "node",
                     graph=ring(n))
 kw = dict(engine=eng, r=r, t_outer=4, schedule=np.array([1, 2, 3, 3]))
-texts, jit = [], jax.jit
-
-
-def lowering_jit(fn):       # the program sdot_spmd makes, lowered as run
-    def call(*args):
-        texts.append(jit(fn).lower(*args).as_text(debug_info=True))
-        return jit(fn)(*args)
-    return call
-
-
-jax.jit = lowering_jit
 sdot_mod.sdot_spmd(covs=covs, **kw).q_nodes.block_until_ready()
-jax.jit = jit
-text, = texts
+program, = eng._spmd_programs.values()   # the one the call above built
+S = jax.ShapeDtypeStruct
+text = program.lower(covs, S((n, d, r), jnp.float32), S((4,), jnp.int32),
+                     eng.debias_table(3), S((d, r), jnp.float32)
+                     ).as_text(debug_info=True)
 out = tempfile.mkdtemp()
 jax.profiler.start_trace(out)
 sdot_mod.sdot_spmd(covs=covs, **kw).q_nodes.block_until_ready()
@@ -186,11 +178,12 @@ def test_traced_sdot_spmd_writes_its_spans_and_scopes():
     assert set(out["spans"]) == SPMD_SPANS
     assert out["spans"]["sdot_spmd.solve"] == {"rounds_run": 12,
                                                "rounds_needed": 9}
-    assert out["spans"]["sdot_spmd.call"] == {"jit_miss": 1}
+    # the traced call reuses the program the first call built
+    assert out["spans"]["sdot_spmd.call"] == {"jit_miss": 0}
     assert out["scopes"] == sorted(SCOPES)
-    # the lowering above ran sdot_spmd once more, outside the trace
+    # the first call ran sdot_spmd once more, outside the trace
     assert out["counts"] == {"sdot_spmd_solve_total": 2,
                              "sdot_spmd_solve_rounds_run_total": 24,
                              "sdot_spmd_solve_rounds_needed_total": 18,
                              "sdot_spmd_call_total": 2,
-                             "sdot_spmd_call_jit_miss_total": 2}
+                             "sdot_spmd_call_jit_miss_total": 1}
