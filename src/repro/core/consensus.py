@@ -247,16 +247,13 @@ def consensus_schedule(kind: str, t_outer: int, t_max: int = 50, cap: Optional[i
 def _record_engine_metrics(sw: SparseW) -> None:
     """Publish a sparse engine's structure to the obs metrics registry
     (visible in ``python -m repro.obs summary``/``prom``): nnz/density
-    gauges, plus a counter for the kernel path this process would select
-    for its gossip rounds (host-side mirror of the traced dispatch)."""
-    from ..kernels import ops as kops
+    gauges. The kernel path depends on the payload width, which only a
+    solve knows, so ``sdot`` counts it per solve (``ell_pallas_rounds``)."""
     from ..obs import metrics
     reg = metrics()
     reg.gauge("gossip_sparse_nnz").set(sw.nnz)
     reg.gauge("gossip_sparse_density").set(sw.density)
     reg.gauge("gossip_sparse_ell_width").set(sw.ell_width)
-    path = kops.ell_spmm_path(sw.n, sw.ell_width, 1)
-    reg.counter(f"gossip_kernel_{path}_total").inc()
     if sw.payload_dtype is not None:
         reg.counter("gossip_bf16_engines_total").inc()
 
@@ -299,6 +296,12 @@ class DenseConsensus:
     @property
     def is_sparse(self) -> bool:
         return self._sparse
+
+    def gossip_path(self, width: int) -> Optional[str]:
+        """The path of one sparse gossip round over a payload of ``width``
+        columns ('pallas' or a fallback: ``SparseW.kernel_path``), or None
+        on dense mixing."""
+        return self._w.kernel_path(width) if self._sparse else None
 
     @property
     def payload_bytes_per_elem(self) -> float:

@@ -469,6 +469,12 @@ class FaultyConsensus:
     def is_sparse(self) -> bool:
         return self._sparse
 
+    def gossip_path(self, width: int) -> Optional[str]:
+        """The path of one sparse gossip round over a payload of ``width``
+        columns ('pallas' or a fallback: ``SparseW.kernel_path``), or None
+        on dense mixing."""
+        return self._w.kernel_path(width) if self._sparse else None
+
     @property
     def payload_bytes_per_elem(self) -> float:
         """Wire bytes per payload element (2.0 under bf16 gossip)."""

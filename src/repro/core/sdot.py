@@ -427,7 +427,10 @@ def sdot(
     The fused run is one ``sdot.solve`` host span on the profiler's clock
     (``obs.trace_span``) whose counts are the gossip rounds the masked scan
     runs (``rounds_run``, t_outer * t_max) and those the schedule asks for
-    (``rounds_needed``); building the Program is ``sdot.program`` (with
+    (``rounds_needed``), and on a sparse engine those the Pallas ELL
+    kernel runs (``ell_pallas_rounds``: all of them where
+    ``engine.gossip_path`` at the payload width the solve mixes, d r, is
+    'pallas', else 0); building the Program is ``sdot.program`` (with
     ``sdot.prepare`` in it), and the runtime adds its own spans.
     """
     # async / faulty engines get their own whole-run scan (the RNG key —
@@ -442,9 +445,15 @@ def sdot(
                     covs=covs, data=data, engine=engine, r=r,
                     t_outer=t_outer, schedule=schedule, t_c=t_c,
                     q_init=q_init, q_true=q_true, seed=seed)
-            span.count(
-                rounds_run=program.t_outer * dict(program.statics)["t_max"],
-                rounds_needed=int(program.xs.sum()))
+            rounds_run = program.t_outer * dict(program.statics)["t_max"]
+            counts = dict(rounds_run=rounds_run,
+                          rounds_needed=int(program.xs.sum()))
+            if getattr(engine, "is_sparse", False):
+                d = covs.shape[1] if covs is not None else data[0].shape[0]
+                counts["ell_pallas_rounds"] = (
+                    rounds_run if engine.gossip_path(d * r) == "pallas"
+                    else 0)
+            span.count(**counts)
             return runtime.run_monolithic(program)
 
     prep = _prepare_sdot(covs=covs, data=data, engine=engine, r=r,

@@ -261,7 +261,9 @@ class SparseW:
         When the cached dense mirror is present (hub-heavy graphs past the
         CPU crossover — see ``kernels/ops.ell_densify_wins``) the round is
         the BLAS matmul against the mirror; ``use_pallas=True`` still
-        forces the ELL kernel for kernel-level tests."""
+        forces the ELL kernel for kernel-level tests. The ELL round runs
+        under the device scope ``gossip.ell_spmm``, inside the caller's
+        (``sdot.gossip`` in S-DOT's outer body)."""
         zf = z.reshape(self.n, -1)
         if self.dense_off is not None and not use_pallas:
             z_src = (zf if self.payload_dtype is None
@@ -270,9 +272,11 @@ class SparseW:
                    * zf.astype(jnp.float32)
                    + self.dense_off @ z_src.astype(jnp.float32))
         else:
-            out = kops.ell_spmm(self.ell_idx, self.ell_val, self.diag, zf,
-                                payload_dtype=self.payload_dtype,
-                                use_pallas=use_pallas, interpret=interpret)
+            with jax.named_scope("gossip.ell_spmm"):
+                out = kops.ell_spmm(self.ell_idx, self.ell_val, self.diag,
+                                    zf, payload_dtype=self.payload_dtype,
+                                    use_pallas=use_pallas,
+                                    interpret=interpret)
         return out.astype(z.dtype).reshape(z.shape)
 
     def offdiag_mix(self, diag: jnp.ndarray, val: jnp.ndarray,
@@ -282,9 +286,19 @@ class SparseW:
         round by masking ``ell_val`` and returning dropped mass to the
         diagonal, then mix through this hook."""
         zf = z.reshape(self.n, -1)
-        out = kops.ell_spmm(self.ell_idx, val, diag, zf,
-                            payload_dtype=self.payload_dtype)
+        with jax.named_scope("gossip.ell_spmm"):
+            out = kops.ell_spmm(self.ell_idx, val, diag, zf,
+                                payload_dtype=self.payload_dtype)
         return out.astype(z.dtype).reshape(z.shape)
+
+    def kernel_path(self, k: int) -> str:
+        """The path ``ops.ell_spmm`` takes for one of this matrix's rounds
+        over a payload of ``k`` columns (``ops.ell_spmm_path``, the
+        host-side mirror of its traced dispatch): 'pallas' or a fallback.
+        The kernel holds the whole payload in VMEM, so the answer depends
+        on the width mixed."""
+        return kops.ell_spmm_path(self.n, self.ell_width, k,
+                                  payload_dtype=self.payload_dtype)
 
     # -- stats / views (host-side) ------------------------------------------
     @property

@@ -254,13 +254,14 @@ def test_sparse_engine_records_metrics():
         return {k: v["value"] for k, v in reg.snapshot().items()
                 if k.startswith("gossip_")}
 
-    before = values()
     eng = SparseConsensus(_graph())
     after = values()
     assert after["gossip_sparse_nnz"] == eng._w.nnz
     assert 0 < after["gossip_sparse_density"] <= 1
-    key = f"gossip_kernel_{ell_spmm_path(eng._w.n, eng._w.ell_width, 1)}_total"
-    assert after[key] > before.get(key, 0)
+    assert after["gossip_sparse_ell_width"] == eng._w.ell_width
+    # the kernel path depends on the payload width, which the engine does
+    # not know: no count claims one (a solve counts it, at its width)
+    assert not [k for k in after if k.startswith("gossip_kernel_")]
 
 
 # ---------------------------------------------------------------------------

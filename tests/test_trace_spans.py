@@ -20,6 +20,7 @@ from repro.core import sdot as sdot_mod
 from repro.core.async_gossip import AsyncConsensus
 from repro.core.consensus import DenseConsensus
 from repro.core.netfaults import FaultyConsensus, NetFaultModel
+from repro.core.sparse import SparseW
 from repro.core.topology import erdos_renyi
 from repro.obs.registry import MetricsRegistry
 
@@ -99,6 +100,59 @@ def test_traced_sdot_writes_its_spans_and_counts(tmp_path, registry):
                       "runtime_dispatch_jit_miss_total": 1}
 
 
+@pytest.mark.parametrize("engine, kernel, pallas_rounds", [
+    ("dense", None, None),
+    # on the CPU the ELL round takes a fallback, so no round is the kernel's
+    ("sparse", None, 0),
+    ("sparse", "pallas", 24),
+    ("faulty-sparse", "pallas", 24),
+], ids=["dense", "sparse-fallback", "sparse-kernel", "faulty-sparse-kernel"])
+def test_sdot_solve_counts_ell_rounds_on_a_sparse_engine(
+        registry, monkeypatch, engine, kernel, pallas_rounds):
+    g = erdos_renyi(N, 0.6, seed=1)
+    eng = (FaultyConsensus(graph=g, faults=NetFaultModel(p_drop=0.2),
+                           seed=0, sparse=True)
+           if engine == "faulty-sparse"
+           else DenseConsensus(g, sparse=engine == "sparse"))
+    if kernel:
+        widths = []
+
+        def path(self, k):
+            widths.append(k)
+            return kernel
+        monkeypatch.setattr(SparseW, "kernel_path", path)
+    sdot_mod.sdot(covs=_covs(), engine=eng, r=R, t_outer=6,
+                  schedule=np.array([1, 2, 3, 4, 4, 4])
+                  ).q_nodes.block_until_ready()
+    counts = {k: v["value"] for k, v in registry.snapshot().items()
+              if k.startswith("sdot_solve")}
+    want = {"sdot_solve_total": 1, "sdot_solve_rounds_run_total": 24,
+            "sdot_solve_rounds_needed_total": 18}
+    if pallas_rounds is not None:
+        want["sdot_solve_ell_pallas_rounds_total"] = pallas_rounds
+    assert counts == want
+    if kernel:
+        assert widths == [D * R]      # the payload the solve mixes
+
+
+def test_kernel_path_is_counted_at_the_width_mixed(monkeypatch):
+    """At N=10,000 and ELL width 12 on a TPU, the path of a d r = 128
+    payload is the kernel's; a payload whose double-buffered VMEM passes
+    the kernel's guard takes a fallback, which a count at width 1 would
+    have called the kernel's."""
+    from repro.kernels import ops as kops
+
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
+    n, width = 10_000, 12
+    sw = SparseW(jnp.zeros((n, width), jnp.int32), jnp.zeros((n, width)),
+                 jnp.ones((n,)), jnp.zeros((n,), jnp.int32), n, width)
+    assert sw.kernel_path(128) == "pallas"        # 10.8 MB of VMEM
+    with pytest.warns(UserWarning, match="guard"):
+        wide = sw.kernel_path(1024)               # 82.9 MB > 40 MiB
+    assert wide.startswith("fallback_")
+    assert sw.kernel_path(1) == "pallas"
+
+
 def _faulty():
     return FaultyConsensus(graph=erdos_renyi(N, 0.6, seed=1),
                            faults=NetFaultModel(p_drop=0.2), seed=0)
@@ -124,6 +178,23 @@ def test_compiled_program_names_each_step(engine, operand, scopes):
     text = runtime.lower_monolithic(prog).compile().as_text()
     names = set(re.findall(r'op_name="[^"]*?(sdot\.[a-z]+)', text))
     assert set(scopes) <= names
+
+
+def test_compiled_sparse_program_names_the_ell_round():
+    """On a sparse engine every gossip round's ops sit under
+    ``gossip.ell_spmm``, inside ``sdot.gossip``. (At 300 nodes the ELL
+    width is far below the CPU's dense-mirror crossover, so the rounds are
+    ELL rounds here too.)"""
+    from repro.core.topology import watts_strogatz
+
+    eng = DenseConsensus(watts_strogatz(300, k=6, p=0.1, seed=1))
+    assert eng.is_sparse and eng._w.dense_off is None
+    covs = jnp.broadcast_to(_covs()[0], (300, D, D))
+    prog = sdot_mod.sdot_program(covs=covs, engine=eng, r=R, t_outer=3,
+                                 t_c=4)
+    text = runtime.lower_monolithic(prog).compile().as_text()
+    paths = re.findall(r'op_name="([^"]*gossip\.ell_spmm[^"]*)"', text)
+    assert paths and all("sdot.gossip/" in p for p in paths)
 
 
 SPMD_CHILD = r'''
