@@ -38,6 +38,7 @@ one compiled SPMD program instead of one collective dispatch per iteration.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -78,17 +79,34 @@ def local_cov_apply(covs: jnp.ndarray, q_nodes: jnp.ndarray) -> jnp.ndarray:
     return jnp.einsum("nde,ner->ndr", covs, q_nodes, precision=PRECISION)
 
 
-def _stack_data(xs: Sequence[jnp.ndarray]):
-    """Zero-pad ragged node blocks (d, n_i) to one (N, d, n_max) stack.
+def _stack_data(xs: Sequence[jnp.ndarray], r: int):
+    """Zero-pad ragged node blocks (d, n_i) to one (N, d, width) stack.
 
-    Padding is exact for the gram apply (padded columns are null in both
-    matmuls); the true n_i go along for the normalizer.
+    Where the batched gram apply runs its Pallas kernel for (d, r), the
+    width is the one the kernel tiles exactly (``kops.gram_tiling(n_max)``),
+    so the apply in every outer iteration pads nothing; elsewhere it is
+    n_max. Padding is exact for the gram apply (padded columns are null in
+    both matmuls); the true n_i go along for the normalizer. Returns
+    ``(stack, n_true, tiled)``, ``tiled`` whether the stack has the
+    kernel's width.
     """
     n_true = np.array([x.shape[1] for x in xs], np.float32)
     n_max = int(n_true.max())
-    stack = jnp.stack([
-        jnp.pad(x, ((0, 0), (0, n_max - x.shape[1]))) for x in xs])
-    return stack, jnp.asarray(n_true)
+    width, block_n = kops.gram_tiling(n_max)
+    tiled = kops.batched_gram_apply_path(xs[0].shape[0], r,
+                                         block_n) == "pallas"
+    if not tiled:
+        width = n_max
+    return _pad_stack(list(xs), width), jnp.asarray(n_true), tiled
+
+
+@functools.partial(jax.jit, static_argnames="width")
+def _pad_stack(xs: list, width: int) -> jnp.ndarray:
+    """Blocks (d, n_i) zero-padded to (N, d, width) in one program, which
+    writes the stack alone: eager pads and a stack hold two more copies
+    of it on the device, and those set the solve's peak memory."""
+    return jnp.stack([jnp.pad(x, ((0, 0), (0, width - x.shape[1])))
+                      for x in xs])
 
 
 def _apply_operand(operand, mode: str, q_nodes):
@@ -272,11 +290,18 @@ def sdot_program(
     Program run under any driver starts from literally the same device
     values. ``runtime.run_monolithic`` reproduces ``sdot(fused=True)``;
     ``runtime.run_chunked`` is the restartable twin (streaming/resume.py).
+
+    With ``data`` the ``sdot.prepare`` span counts the sample columns of
+    the stack built (``stack_cols``) and whether that is the gram kernel's
+    tiled width, so the apply pads nothing (``stack_tiled``, 1 or 0).
     """
-    with trace_span("sdot.prepare"):
+    with trace_span("sdot.prepare") as span:
         prep = _prepare_sdot(covs=covs, data=data, engine=engine, r=r,
                              t_outer=t_outer, schedule=schedule, t_c=t_c,
                              q_init=q_init, q_true=q_true, seed=seed)
+        if prep["mode"] == "data":
+            span.count(stack_cols=prep["operand"][0].shape[2],
+                       stack_tiled=int(prep["stack_tiled"]))
     n, d = prep["n"], prep["d"]
     t_max, trace_err, q_arg = prep["t_max"], prep["trace_err"], prep["q_arg"]
     sched_np = prep["sched_np"]
@@ -389,12 +414,15 @@ def _prepare_sdot(*, covs, data, engine, r, t_outer, schedule, t_c, q_init,
     t_max = int(sched_np.max()) if t_outer else 0
     trace_err = q_true is not None
     q_arg = q_true if trace_err else jnp.zeros((d, r), q_nodes.dtype)
+    stack_tiled = False
     if covs is not None:
         operand, mode = covs, "cov"
     else:
-        operand, mode = _stack_data(data), "data"
+        x_stack, n_true, stack_tiled = _stack_data(data, r)
+        operand, mode = (x_stack, n_true), "data"
     return dict(
-        n=n, d=d, operand=operand, mode=mode, q_nodes=q_nodes,
+        n=n, d=d, operand=operand, mode=mode, stack_tiled=stack_tiled,
+        q_nodes=q_nodes,
         schedule=schedule, sched_np=sched_np,
         sched_dev=jnp.asarray(sched_np, jnp.int32), t_max=t_max,
         trace_err=trace_err, q_arg=q_arg, is_async=is_async,

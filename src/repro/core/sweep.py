@@ -349,7 +349,7 @@ def sdot_sweep(
             case_axes = (None, 0, 0, None, 0)
             mode = "cov"
         else:
-            x_stack, n_true = _stack_data(data)
+            x_stack, n_true, _ = _stack_data(data, r)
             operands = (x_stack, n_true, ws, tables, q_arg, masks)
             case_axes = (None, None, 0, 0, None, 0)
             mode = "data"

@@ -35,7 +35,7 @@ __all__ = ["gram_apply", "batched_gram_apply", "batched_slab_tq",
            "batched_slab_apply", "grid_block_tq", "grid_block_apply",
            "gram_qr", "flash_attention", "ell_spmm", "ell_spmm_path",
            "ell_block_rows", "ell_densify_wins", "batched_gram_apply_path",
-           "on_tpu"]
+           "gram_tiling", "on_tpu"]
 
 
 def on_tpu() -> bool:
@@ -104,6 +104,19 @@ def gram_apply(x: jnp.ndarray, q: jnp.ndarray, *, block_n: int = 512,
     return (v / n).astype(q.dtype)
 
 
+def gram_tiling(n: int) -> tuple[int, int]:
+    """``(width, block_n)``: how the batched gram kernel tiles ``n``
+    sample columns. The fewest column blocks of at most 512 that hold n
+    rounded up to 128 lanes, each as narrow as a multiple of 128 allows,
+    so fewer than 128 zero columns a block pad n (662 -> 2 x 384 = 768,
+    not 1,024). A stack built at ``width`` tiles to itself: its
+    ``block_n`` divides it and nothing is padded."""
+    blocks = -(-n // 512)
+    per_block = -(-n // blocks)
+    block_n = -(-per_block // 128) * 128
+    return blocks * block_n, block_n
+
+
 def batched_gram_apply_path(d: int, r: int, block_n: int = 512,
                             use_pallas: bool | None = None) -> str:
     """Which path ``batched_gram_apply`` takes for (d, r): 'pallas' |
@@ -115,7 +128,7 @@ def batched_gram_apply_path(d: int, r: int, block_n: int = 512,
 @functools.partial(jax.jit,
                    static_argnames=("block_n", "use_pallas", "interpret"))
 def batched_gram_apply(x_stack: jnp.ndarray, q_stack: jnp.ndarray,
-                       n_true: jnp.ndarray, *, block_n: int = 512,
+                       n_true: jnp.ndarray, *, block_n: int | None = None,
                        use_pallas: bool | None = None,
                        interpret: bool | None = None) -> jnp.ndarray:
     """V[i] = X_i (X_i^T Q_i) / n_i — batched Step 5 for all nodes at once.
@@ -125,6 +138,11 @@ def batched_gram_apply(x_stack: jnp.ndarray, q_stack: jnp.ndarray,
     n_true). This is the dispatch point for the fused S-DOT executor's raw-
     data path: one call per outer iteration regardless of N.
 
+    ``block_n=None`` takes ``gram_tiling(n)``'s block. A stack already
+    built at that width (``sdot._stack_data`` builds it so on the kernel's
+    path) goes to the kernel as it is; any other is zero-padded here to a
+    multiple of the block, on every call.
+
     ``use_pallas=None`` auto-selects: the Pallas (node, column-block) kernel
     on TPU, the fused-einsum oracle elsewhere (interpret-mode Pallas unrolls
     the grid at trace time, which bloats the fused scan's XLA program on
@@ -132,6 +150,8 @@ def batched_gram_apply(x_stack: jnp.ndarray, q_stack: jnp.ndarray,
     exercise the kernel itself off-TPU.
     """
     n_nodes, d, n = x_stack.shape
+    if block_n is None:
+        block_n = gram_tiling(n)[1]
     if batched_gram_apply_path(d, q_stack.shape[-1], block_n,
                                use_pallas) != "pallas":
         return ref.batched_gram_apply_ref(x_stack, q_stack, n_true)

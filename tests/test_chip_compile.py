@@ -51,9 +51,14 @@ def one_chip():
 F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
 CASES = {
     # chip_smoke phase (b), LFW row: N=20, d=2914, r=7, 13,233 samples
-    # (662 per node, padded to two 512-column blocks)
+    # (662 per node, padded to two 512-column blocks, as with block_n=512)
     "gram_lfw": (batched_gram_apply_pallas,
                  [((20, 2914, 1024), F32), ((20, 2914, 7), F32)], {}),
+    # the same row as the S-DOT stack is built on the chip: 662 columns
+    # tiled once to two 384-column blocks (ops.gram_tiling)
+    "gram_lfw_tiled": (batched_gram_apply_pallas,
+                       [((20, 2914, 768), F32), ((20, 2914, 7), F32)],
+                       {"block_n": 384}),
     # phase (a)'s ImageNet row as raw data: N=100, d=1024, r=5
     "gram_imagenet": (batched_gram_apply_pallas,
                       [((100, 1024, 512), F32), ((100, 1024, 5), F32)], {}),

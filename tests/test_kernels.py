@@ -95,6 +95,97 @@ def test_batched_gram_apply_ref_fallback_matches_kernel():
                                atol=1e-5)
 
 
+@pytest.mark.parametrize("n", [1, 127, 128, 500, 512, 513, 640, 662, 1024,
+                               1500])
+def test_gram_tiling_covers_n_in_lane_aligned_blocks(n):
+    width, block_n = ops.gram_tiling(n)
+    blocks = width // block_n
+    assert width >= n
+    assert width % 128 == 0 and block_n % 128 == 0
+    assert width % block_n == 0
+    assert block_n <= 512
+    assert width < n + 128 * blocks
+    # a stack built at the width tiles to itself, and an untiled one pads
+    # to the same width
+    assert ops.gram_tiling(width) == (width, block_n)
+    assert -(-n // block_n) * block_n == width
+
+
+def test_gram_tiling_of_the_lfw_row():
+    assert ops.gram_tiling(662) == (768, 384)
+    assert ops.gram_tiling(500) == (512, 512)
+
+
+def _primitives(jaxpr):
+    """Names of every primitive in ``jaxpr`` and the jaxprs inside it."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    names |= _primitives(inner)
+    return names
+
+
+@pytest.mark.parametrize("n_true", [[662, 661, 662], [500, 431]],
+                         ids=["two-blocks", "one-block"])
+def test_batched_gram_apply_on_a_tiled_stack_pads_nothing(n_true):
+    """A stack built at ``gram_tiling``'s width goes to the kernel as it
+    is: no pad in the traced apply, and the per-node oracle's answer."""
+    rng = np.random.default_rng(2)
+    n_nodes, d, r = len(n_true), 16, 3
+    width, _ = ops.gram_tiling(max(n_true))
+    x_stack = np.zeros((n_nodes, d, width), np.float32)
+    for i, ni in enumerate(n_true):
+        x_stack[i, :, :ni] = rng.standard_normal((d, ni))
+    x_stack = jnp.asarray(x_stack)
+    q = jnp.asarray(rng.standard_normal((n_nodes, d, r)), jnp.float32)
+    nt = jnp.asarray(n_true, jnp.float32)
+
+    def apply(x, q, nt):
+        return ops.batched_gram_apply(x, q, nt, use_pallas=True,
+                                      interpret=True)
+
+    jaxpr = jax.make_jaxpr(apply)(x_stack, q, nt).jaxpr
+    prims = _primitives(jaxpr)
+    assert "pallas_call" in prims
+    assert "pad" not in prims
+    # an untiled stack is still padded, on every call
+    assert "pad" in _primitives(
+        jax.make_jaxpr(apply)(x_stack[:, :, :max(n_true)], q, nt).jaxpr)
+    out = apply(x_stack, q, nt)
+    want = ref.batched_gram_apply_ref(x_stack, q, nt)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-4,
+                               atol=2e-4)
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["fallback", "pallas"])
+def test_stack_data_width_follows_the_apply_path(monkeypatch, kernel):
+    """``_stack_data`` builds n_max columns where the apply takes the
+    oracle, and the kernel's tiled width where it takes the kernel; the
+    columns past each block's n_i are zero either way."""
+    from repro.core.sdot import _stack_data
+
+    if kernel:
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    rng = np.random.default_rng(3)
+    n_true, d, r = [662, 661, 640], 16, 7
+    xs = [jnp.asarray(rng.standard_normal((d, ni)), jnp.float32)
+          for ni in n_true]
+    stack, nt, tiled = _stack_data(xs, r)
+    assert tiled is kernel
+    assert stack.shape == (len(xs), d, 768 if kernel else 662)
+    np.testing.assert_array_equal(np.asarray(nt), n_true)
+    for i, ni in enumerate(n_true):
+        np.testing.assert_array_equal(np.asarray(stack[i, :, :ni]),
+                                      np.asarray(xs[i]))
+        assert not np.asarray(stack[i, :, ni:]).any()
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------

@@ -100,6 +100,28 @@ def test_traced_sdot_writes_its_spans_and_counts(tmp_path, registry):
                       "runtime_dispatch_jit_miss_total": 1}
 
 
+@pytest.mark.parametrize("kernel", [False, True], ids=["cpu", "kernel"])
+def test_sdot_prepare_counts_the_stack_it_builds(registry, monkeypatch,
+                                                 kernel):
+    """On the data operand ``sdot.prepare`` counts the stack's columns and
+    whether they are the gram kernel's tiled width: n_max and 0 where the
+    apply takes the oracle, 768 and 1 for 662 samples on the kernel's path
+    (the program is built, not run, since the kernel needs the chip)."""
+    from repro.kernels import ops
+
+    if kernel:
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    rng = np.random.default_rng(0)
+    data = [jnp.asarray(rng.normal(size=(D, 662 - i % 2)), jnp.float32)
+            for i in range(N)]
+    sdot_mod.sdot_program(data=data, engine=DenseConsensus(
+        erdos_renyi(N, 0.6, seed=1)), r=R, t_outer=2)
+    counts = {k: v["value"] for k, v in registry.snapshot().items()}
+    assert counts == {"sdot_prepare_total": 1,
+                      "sdot_prepare_stack_cols_total": 768 if kernel else 662,
+                      "sdot_prepare_stack_tiled_total": int(kernel)}
+
+
 @pytest.mark.parametrize("engine, kernel, pallas_rounds", [
     ("dense", None, None),
     # on the CPU the ELL round takes a fallback, so no round is the kernel's
