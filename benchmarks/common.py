@@ -9,14 +9,12 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, Optional
 
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core.consensus import DenseConsensus, consensus_schedule
 from repro.core.linalg import eigh_topr
-from repro.core.topology import Graph, erdos_renyi, ring, star
+from repro.core.topology import Graph
 from repro.data.pipeline import gaussian_eigengap_data, partition_samples
 
 
@@ -83,12 +81,6 @@ def sample_problem(*, d: int, r: int, n_nodes: int, n_per: int, gap: float,
 def p2p_per_node_k(graph: Graph, rounds_total: int) -> float:
     """Average per-node P2P messages (K) after ``rounds_total`` gossip rounds."""
     return float(graph.adjacency.sum() / graph.n_nodes) * rounds_total / 1e3
-
-
-def schedule_rounds(kind: str, t_outer: int, t_max: int = 50,
-                    cap: Optional[int] = None) -> int:
-    """Total consensus rounds for a schedule over t_outer outer iterations."""
-    return int(consensus_schedule(kind, t_outer, t_max=t_max, cap=cap).sum())
 
 
 # The paper's standard schedule set (Tables I-IV; cap = the experiment's

@@ -380,8 +380,8 @@ class SparseConsensus(DenseConsensus):
     ``payload_dtype="bfloat16"`` additionally quantizes the gossip
     payload (the neighbor messages, not each node's own state) to bf16
     with f32 accumulation; the comm ledger then prices bytes at 2/elem
-    (``benchmarks/sparse_gossip_bench.py`` measures the accuracy-vs-bytes
-    curve this trades on).
+    (``tests/test_sparse.py`` pins the quantized round and the halved
+    ledger bytes).
     """
 
     def __post_init__(self):

@@ -55,7 +55,6 @@ __all__ = ["SparseW", "auto_sparse"]
 # existing N <= 200 result keeps the dense einsum bit for bit.
 AUTO_MIN_NODES = 256
 AUTO_MAX_DENSITY = 0.05
-_ENV_FLAG = "REPRO_SPARSE_GOSSIP"
 
 
 def auto_sparse(n_nodes: int, density: float,
@@ -64,18 +63,10 @@ def auto_sparse(n_nodes: int, density: float,
 
     ``True``/``False`` are explicit; ``None`` auto-enables when the
     network is both large (>= AUTO_MIN_NODES) and sparse
-    (<= AUTO_MAX_DENSITY off-diagonal density). ``REPRO_SPARSE_GOSSIP=0``
-    or ``=1`` overrides the auto rule from the environment (explicit
-    arguments still win).
+    (<= AUTO_MAX_DENSITY off-diagonal density).
     """
     if sparse is not None:
         return bool(sparse)
-    import os
-    env = os.environ.get(_ENV_FLAG, "").strip().lower()
-    if env in ("0", "false", "off"):
-        return False
-    if env in ("1", "true", "on"):
-        return True
     return n_nodes >= AUTO_MIN_NODES and density <= AUTO_MAX_DENSITY
 
 
@@ -91,27 +82,15 @@ class SparseW:
     n: int                    # static: node count
     ell_width: int            # static: L (max row degree, >= 1)
     payload_dtype: Optional[str] = None   # static: e.g. "bfloat16"
-    # (N, N) f32 off-diagonal mirror, present only past the measured CPU
-    # crossover L ~ N/11 (hub-heavy graphs pad ELL toward dense work with
-    # worse constants than BLAS): materialized ONCE at construction so the
-    # scatter is hoisted out of every fused scan, and mixed through by
-    # ``mix`` instead of the ELL kernel. Off-diagonal only — the separate
-    # diagonal keeps bf16 payload semantics (neighbor messages quantized,
-    # own state full precision).
-    dense_off: Optional[jnp.ndarray] = None
 
     # -- pytree protocol ----------------------------------------------------
     def tree_flatten(self):
-        return ((self.ell_idx, self.ell_val, self.diag, self.row_nnz,
-                 self.dense_off),
+        return ((self.ell_idx, self.ell_val, self.diag, self.row_nnz),
                 (self.n, self.ell_width, self.payload_dtype))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        ell_idx, ell_val, diag, row_nnz, dense_off = children
-        n, ell_width, payload_dtype = aux
-        return cls(ell_idx, ell_val, diag, row_nnz, n, ell_width,
-                   payload_dtype, dense_off)
+        return cls(*children, *aux)
 
     # -- constructors -------------------------------------------------------
     @classmethod
@@ -151,15 +130,9 @@ class SparseW:
         ell_val = np.zeros((n, ell_width), np.float32)
         ell_idx[rows, slots] = cols.astype(np.int32)
         ell_val[rows, slots] = w[rows, cols].astype(np.float32)
-        dense_off = None
-        if not kops.on_tpu() and kops.ell_densify_wins(n, ell_width):
-            off = w.astype(np.float32).copy()
-            np.fill_diagonal(off, 0.0)
-            dense_off = jnp.asarray(off)
         return cls(jnp.asarray(ell_idx), jnp.asarray(ell_val),
                    jnp.asarray(np.diagonal(w).astype(np.float32)),
-                   jnp.asarray(row_nnz), n, ell_width, payload_dtype,
-                   dense_off)
+                   jnp.asarray(row_nnz), n, ell_width, payload_dtype)
 
     @classmethod
     def from_graph(cls, graph, weights: Optional[np.ndarray] = None, *,
@@ -194,24 +167,15 @@ class SparseW:
                     jnp.pad(s.ell_val, ((0, 0), (0, extra))))
 
         idx, val = zip(*(widen(s) for s in sws))
-        # mirror presence must be uniform across the batch (pytree
-        # structure); the crossover is monotone in L, so decide by the
-        # common (max) width and fill in any member's missing mirror
-        dense_off = None
-        if not kops.on_tpu() and kops.ell_densify_wins(n, width):
-            dense_off = jnp.stack([s.dense_off if s.dense_off is not None
-                                   else s._scatter_off() for s in sws])
         return cls(jnp.stack(idx), jnp.stack(val),
                    jnp.stack([s.diag for s in sws]),
-                   jnp.stack([s.row_nnz for s in sws]), n, width, pd,
-                   dense_off)
+                   jnp.stack([s.row_nnz for s in sws]), n, width, pd)
 
     def __getitem__(self, k) -> "SparseW":
         """Index the leading batch axis of a ``stack``-ed SparseW."""
-        off = None if self.dense_off is None else self.dense_off[k]
         return SparseW(self.ell_idx[k], self.ell_val[k], self.diag[k],
                        self.row_nnz[k], self.n, self.ell_width,
-                       self.payload_dtype, off)
+                       self.payload_dtype)
 
     # -- array-protocol shims (the surface consensus.py relies on) ----------
     @property
@@ -229,7 +193,7 @@ class SparseW:
             return self
         return SparseW(self.ell_idx, self.ell_val.astype(dtype),
                        self.diag.astype(dtype), self.row_nnz, self.n,
-                       self.ell_width, self.payload_dtype, self.dense_off)
+                       self.ell_width, self.payload_dtype)
 
     @property
     def T(self) -> "SparseW":
@@ -238,16 +202,7 @@ class SparseW:
 
     def with_payload_dtype(self, payload_dtype: Optional[str]) -> "SparseW":
         return SparseW(self.ell_idx, self.ell_val, self.diag, self.row_nnz,
-                       self.n, self.ell_width, payload_dtype, self.dense_off)
-
-    def _scatter_off(self) -> jnp.ndarray:
-        """Scatter the ELL slots to the (N, N) off-diagonal matrix (padded
-        slots self-point with weight 0, so scatter-add is exact)."""
-        rows = jnp.broadcast_to(
-            jnp.arange(self.n, dtype=jnp.int32)[:, None],
-            (self.n, self.ell_width))
-        return jnp.zeros((self.n, self.n), jnp.float32).at[
-            rows, self.ell_idx].add(self.ell_val.astype(jnp.float32))
+                       self.n, self.ell_width, payload_dtype)
 
     # -- the gossip round ---------------------------------------------------
     def mix(self, z: jnp.ndarray, *, use_pallas: Optional[bool] = None,
@@ -256,27 +211,14 @@ class SparseW:
         z_{idx_il}`` over an arbitrary payload z: (N, ...). f32
         accumulation; bf16 payload quantization when ``payload_dtype`` is
         set. Traceable — this is the inner op of every fused executor's
-        scan when the engine is sparse.
-
-        When the cached dense mirror is present (hub-heavy graphs past the
-        CPU crossover — see ``kernels/ops.ell_densify_wins``) the round is
-        the BLAS matmul against the mirror; ``use_pallas=True`` still
-        forces the ELL kernel for kernel-level tests. The ELL round runs
-        under the device scope ``gossip.ell_spmm``, inside the caller's
-        (``sdot.gossip`` in S-DOT's outer body)."""
+        scan when the engine is sparse. The round runs under the device
+        scope ``gossip.ell_spmm``, inside the caller's (``sdot.gossip`` in
+        S-DOT's outer body)."""
         zf = z.reshape(self.n, -1)
-        if self.dense_off is not None and not use_pallas:
-            z_src = (zf if self.payload_dtype is None
-                     else zf.astype(self.payload_dtype))
-            out = (self.diag.astype(jnp.float32)[:, None]
-                   * zf.astype(jnp.float32)
-                   + self.dense_off @ z_src.astype(jnp.float32))
-        else:
-            with jax.named_scope("gossip.ell_spmm"):
-                out = kops.ell_spmm(self.ell_idx, self.ell_val, self.diag,
-                                    zf, payload_dtype=self.payload_dtype,
-                                    use_pallas=use_pallas,
-                                    interpret=interpret)
+        with jax.named_scope("gossip.ell_spmm"):
+            out = kops.ell_spmm(self.ell_idx, self.ell_val, self.diag, zf,
+                                payload_dtype=self.payload_dtype,
+                                use_pallas=use_pallas, interpret=interpret)
         return out.astype(z.dtype).reshape(z.shape)
 
     def offdiag_mix(self, diag: jnp.ndarray, val: jnp.ndarray,

@@ -28,9 +28,8 @@ MID-GRID, bitwise equal to the uninterrupted sweep
 
 Compare: the eager zoo runs seeds x cases x t_outer Python iterations with
 a host sync each — the sweep engine runs one dispatch total and the whole
-(C, S, T_o) error tensor comes back in one transfer (benchmarks/
-sweep_bench.py measures the win; tests/test_fused_zoo.py pins sweep ==
-per-seed fused runs).
+(C, S, T_o) error tensor comes back in one transfer (tests/
+test_fused_zoo.py pins sweep == per-seed fused runs).
 """
 from __future__ import annotations
 

@@ -34,7 +34,7 @@ from .slab_ops import (batched_slab_apply_pallas, batched_slab_tq_pallas,
 __all__ = ["gram_apply", "batched_gram_apply", "batched_slab_tq",
            "batched_slab_apply", "grid_block_tq", "grid_block_apply",
            "gram_qr", "flash_attention", "ell_spmm", "ell_spmm_path",
-           "ell_block_rows", "ell_densify_wins", "batched_gram_apply_path",
+           "ell_block_rows", "batched_gram_apply_path",
            "gram_tiling", "on_tpu"]
 
 
@@ -271,20 +271,6 @@ def grid_block_apply(x_grid: jnp.ndarray, s_stack: jnp.ndarray, *,
 # slot-at-a-time scan's O(N K) peak memory.
 _ELL_GATHER_ELEMS = 1 << 25
 
-# Measured CPU crossover: past L ~ N / _ELL_DENSE_RATIO the gather path
-# (O(N L K), poor constants) loses to scatter-to-dense + BLAS matmul
-# (O(N^2 K), great constants). Hub-heavy graphs (Barabasi-Albert) pad ELL
-# to the max degree, so small-N scale-free overlays land here.
-_ELL_DENSE_RATIO = 11
-
-
-def ell_densify_wins(n: int, ell_width: int) -> bool:
-    """Host-side crossover test: for this (N, L) the densified BLAS matmul
-    beats the ELL gather/scan fallbacks, so off-TPU callers that can hoist
-    the scatter (``SparseW`` caches a dense off-diagonal mirror at
-    construction) should mix through the mirror instead."""
-    return ell_width * _ELL_DENSE_RATIO >= n
-
 
 def ell_block_rows(ell_width: int, block_rows: int = 256) -> int:
     """Rows per ELL kernel step: ``block_rows`` halved until the step's
@@ -300,7 +286,7 @@ def ell_spmm_path(n: int, ell_width: int, k: int,
                   payload_dtype: str | None = None,
                   block_rows: int = 256) -> str:
     """Which execution path ``ell_spmm`` will take for these shapes:
-    'pallas' | 'fallback_gather' | 'fallback_scan' | 'fallback_dense'
+    'pallas' | 'fallback_gather' | 'fallback_scan'
     (host-side mirror of the traced dispatch below, for observability and
     benchmarks). The Pallas kernel keeps the whole gather source resident
     and double-buffered, at 2 bytes/elem in bf16 payload mode, and runs
@@ -312,8 +298,6 @@ def ell_spmm_path(n: int, ell_width: int, k: int,
              (ell_spmm_smem_bytes(8, ell_width), _ELL_SMEM_BUDGET, "SMEM"))
     if _guarded_path("ell_spmm", use_pallas, *guard) == "pallas":
         return "pallas"
-    if ell_densify_wins(n, ell_width):
-        return "fallback_dense"
     if n * ell_width * k <= _ELL_GATHER_ELEMS:
         return "fallback_gather"
     return "fallback_scan"
@@ -337,10 +321,9 @@ def ell_spmm(ell_idx: jnp.ndarray, ell_val: jnp.ndarray, diag: jnp.ndarray,
 
     ``use_pallas=None`` auto-selects: the Pallas row-block gather kernel
     on TPU (guarded by the double-buffered payload fitting VMEM), the
-    gather/einsum oracle elsewhere — densifying to a BLAS matmul when the
-    padded width approaches N (hub-heavy graphs) and degrading to a
-    slot-at-a-time scan when the (N, L, K) gathered block would be large
-    (see ``ell_spmm_path``).
+    gather/einsum oracle elsewhere, degrading to a slot-at-a-time scan
+    when the (N, L, K) gathered block would be large (see
+    ``ell_spmm_path``).
     """
     n, k = z.shape
     ell_width = ell_idx.shape[1]
@@ -349,8 +332,6 @@ def ell_spmm(ell_idx: jnp.ndarray, ell_val: jnp.ndarray, diag: jnp.ndarray,
                          block_rows)
     if path == "fallback_gather":
         return ref.ell_spmm_ref(ell_idx, ell_val, diag, z, z_src)
-    if path == "fallback_dense":
-        return ref.ell_spmm_dense_ref(ell_idx, ell_val, diag, z, z_src)
     if path == "fallback_scan":
         return ref.ell_spmm_scan_ref(ell_idx, ell_val, diag, z, z_src)
     interp = (not on_tpu()) if interpret is None else interpret

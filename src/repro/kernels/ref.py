@@ -30,24 +30,6 @@ def ell_spmm_ref(ell_idx: jnp.ndarray, ell_val: jnp.ndarray,
                             precision=PRECISION)
 
 
-def ell_spmm_dense_ref(ell_idx: jnp.ndarray, ell_val: jnp.ndarray,
-                       diag: jnp.ndarray, z_own: jnp.ndarray,
-                       z_src: jnp.ndarray) -> jnp.ndarray:
-    """Densifying twin of ``ell_spmm_ref``: scatters the ELL slots back to
-    an (N, N) off-diagonal matrix and uses the dense matmul. For hub-heavy
-    graphs the padded width L approaches N and the gather path does nearly
-    dense work with far worse constants than BLAS — past the measured CPU
-    crossover (L ~ N/11) the O(N L) scatter + O(N^2 K) matmul is faster.
-    Padded slots self-point with weight 0, so scatter-add is exact."""
-    n = diag.shape[0]
-    rows = jnp.broadcast_to(jnp.arange(n)[:, None], ell_idx.shape)
-    w_off = jnp.zeros((n, n), jnp.float32).at[rows, ell_idx].add(
-        ell_val.astype(jnp.float32))
-    acc = diag.astype(jnp.float32)[:, None] * z_own.astype(jnp.float32)
-    return acc + jnp.matmul(w_off, z_src.astype(jnp.float32),
-                            precision=PRECISION)
-
-
 def ell_spmm_scan_ref(ell_idx: jnp.ndarray, ell_val: jnp.ndarray,
                       diag: jnp.ndarray, z_own: jnp.ndarray,
                       z_src: jnp.ndarray) -> jnp.ndarray:

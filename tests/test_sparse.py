@@ -108,9 +108,7 @@ def test_stack_and_getitem():
 def test_sparsew_is_pytree():
     sw = SparseW.from_graph(_graph())
     leaves, treedef = jax.tree_util.tree_flatten(sw)
-    # 4 ELL children, plus the dense off-diagonal mirror when the graph is
-    # past the densify crossover (None contributes no leaf below it)
-    assert len(leaves) == 4 + (sw.dense_off is not None)
+    assert len(leaves) == 4          # ell_idx, ell_val, diag, row_nnz
     sw2 = jax.tree_util.tree_unflatten(treedef, leaves)
     assert sw2.n == sw.n and sw2.ell_width == sw.ell_width
 
@@ -136,17 +134,12 @@ def test_power_iteration_spectral_gap_matches_exact():
 # ---------------------------------------------------------------------------
 # auto-selection policy
 # ---------------------------------------------------------------------------
-def test_auto_sparse_policy(monkeypatch):
-    monkeypatch.delenv("REPRO_SPARSE_GOSSIP", raising=False)
+def test_auto_sparse_policy():
     assert auto_sparse(AUTO_MIN_NODES, AUTO_MAX_DENSITY) is True
     assert auto_sparse(AUTO_MIN_NODES - 1, AUTO_MAX_DENSITY) is False
     assert auto_sparse(AUTO_MIN_NODES, AUTO_MAX_DENSITY * 2) is False
     assert auto_sparse(16, 0.9, sparse=True) is True     # explicit wins
-    monkeypatch.setenv("REPRO_SPARSE_GOSSIP", "1")
-    assert auto_sparse(16, 0.9) is True
-    assert auto_sparse(16, 0.9, sparse=False) is False   # explicit still wins
-    monkeypatch.setenv("REPRO_SPARSE_GOSSIP", "0")
-    assert auto_sparse(10_000, 0.001) is False
+    assert auto_sparse(10_000, 0.001, sparse=False) is False
 
 
 def test_small_dense_engines_stay_dense():
@@ -197,6 +190,22 @@ def test_ell_spmm_path_policy():
     # huge gather footprint falls back to the slot scan
     assert ell_spmm_path(1 << 20, 64, 64,
                          use_pallas=False) == "fallback_scan"
+
+
+@pytest.mark.parametrize("graph", [
+    topo.barabasi_albert(40, m=3),       # hub rows pad ELL toward N
+    topo.erdos_renyi(40, 0.15),
+], ids=["ba_hubs", "er"])
+def test_wide_ell_mixes_through_the_gather(graph):
+    """However wide the padded ELL gets relative to N, the CPU round is
+    the gather fallback, and it is exact against the dense matrix."""
+    sw = SparseW.from_graph(graph)
+    z = np.random.default_rng(6).standard_normal(
+        (graph.n_nodes, 5)).astype(np.float32)
+    assert ell_spmm_path(sw.n, sw.ell_width, z.shape[1],
+                         use_pallas=False) == "fallback_gather"
+    np.testing.assert_allclose(np.asarray(sw.mix(jnp.asarray(z))),
+                               np.asarray(sw.to_dense()) @ z, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -204,13 +204,11 @@ def test_compiled_program_names_each_step(engine, operand, scopes):
 
 def test_compiled_sparse_program_names_the_ell_round():
     """On a sparse engine every gossip round's ops sit under
-    ``gossip.ell_spmm``, inside ``sdot.gossip``. (At 300 nodes the ELL
-    width is far below the CPU's dense-mirror crossover, so the rounds are
-    ELL rounds here too.)"""
+    ``gossip.ell_spmm``, inside ``sdot.gossip``."""
     from repro.core.topology import watts_strogatz
 
     eng = DenseConsensus(watts_strogatz(300, k=6, p=0.1, seed=1))
-    assert eng.is_sparse and eng._w.dense_off is None
+    assert eng.is_sparse
     covs = jnp.broadcast_to(_covs()[0], (300, D, D))
     prog = sdot_mod.sdot_program(covs=covs, engine=eng, r=R, t_outer=3,
                                  t_c=4)
